@@ -6,7 +6,7 @@ from repro.cluster.controlplane import (
     ReconcileAction,
     ReplicaSet,
 )
-from repro.cluster.dispatcher import DeploymentPlan, Dispatcher
+from repro.cluster.dispatcher import DeploymentPlan, Dispatcher, PlacementInfeasible
 from repro.cluster.events import (
     ClusterEvent,
     LinkDegraded,
@@ -20,7 +20,13 @@ from repro.cluster.engine import (
     ReplicatedServingLoop,
     StageState,
 )
-from repro.cluster.lifecycle import EdgeCluster, InferencePipeline, Node, Pod
+from repro.cluster.lifecycle import (
+    EdgeCluster,
+    InferencePipeline,
+    Node,
+    PipelineDegraded,
+    Pod,
+)
 from repro.cluster.serving import (
     Request,
     ServingLoop,
@@ -47,7 +53,9 @@ __all__ = [
     "NodeFailed",
     "NodeJoined",
     "ObservedState",
+    "PipelineDegraded",
     "PipelinedServingLoop",
+    "PlacementInfeasible",
     "Pod",
     "ReconcileAction",
     "ReplicaSet",

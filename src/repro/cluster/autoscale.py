@@ -32,6 +32,7 @@ import dataclasses
 import math
 from typing import Callable, Sequence
 
+from repro.cluster.dispatcher import PlacementInfeasible
 from repro.obs.stats import percentile
 
 
@@ -65,7 +66,7 @@ class Autoscaler:
     make_control:
         ``(group, replica_index) -> bootstrapped ControlPlane`` -- built by
         ``deploy()`` so the autoscaler stays free of planner/store wiring.
-        May raise ``RuntimeError`` when the group can no longer host the
+        May raise ``PlacementInfeasible`` when the group can no longer host the
         model (e.g. its nodes died while on standby); the group is discarded
         and the next standby group is tried.
     standby_groups:
@@ -189,7 +190,7 @@ class Autoscaler:
             group = self.standby.pop(0)
             try:
                 control = self.make_control(group, len(router.loops))
-            except RuntimeError:
+            except PlacementInfeasible:
                 # the group lost nodes while parked; it cannot host anymore
                 self.discarded.append(group)
                 continue

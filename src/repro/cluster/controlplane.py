@@ -38,7 +38,7 @@ from collections import deque
 from typing import Callable, Sequence
 
 from repro.api.planner import Plan, Planner, ReplicatedPlan
-from repro.cluster.dispatcher import UNSET, Dispatcher
+from repro.cluster.dispatcher import UNSET, Dispatcher, PlacementInfeasible
 from repro.cluster.events import (
     ClusterEvent,
     LinkDegraded,
@@ -181,7 +181,7 @@ class ControlPlane:
             compression_ratio=self.desired.compression_ratio,
         )
         if not plan.feasible:
-            raise RuntimeError(f"version {version} does not fit the cluster")
+            raise PlacementInfeasible(f"version {version} does not fit the cluster")
         return plan
 
     @property
@@ -212,7 +212,7 @@ class ControlPlane:
             self.dispatcher.planner = planner
         try:
             plan = self._configure(self.desired.graph, self.desired.version)
-        except RuntimeError:
+        except PlacementInfeasible:
             self.dispatcher.planner = old_planner  # keep a working strategy
             raise
         for pod in self.pipeline.pods:  # stop the old inference pods
@@ -315,7 +315,7 @@ class ControlPlane:
         # event while the store pointer stays ahead of the deployed version)
         try:
             plan = self._configure(graph, event.version)
-        except RuntimeError as e:
+        except PlacementInfeasible as e:
             return ReconcileAction(
                 event, "noop",
                 f"rejected: {e}; keeping v{self.desired.version}",
@@ -375,7 +375,7 @@ class ControlPlane:
         # cannot host the model, the old pipeline must keep serving
         try:
             plan = self._configure(self.desired.graph, self.desired.version)
-        except RuntimeError as e:
+        except PlacementInfeasible as e:
             return ReconcileAction(
                 event, "noop", f"rejected: {e}; keeping current deployment"
             )
@@ -724,7 +724,7 @@ class ReplicaSet:
         for r in self.live_indices():
             try:
                 actions.extend(self.controls[r].reconcile())
-            except RuntimeError as e:
+            except PlacementInfeasible as e:
                 self.mark_retired(r, str(e))
                 actions.append(self.controls[r].history[-1])
         self.advance_rollout()

@@ -39,6 +39,12 @@ DeploymentPlan = Plan
 UNSET = object()
 
 
+class PlacementInfeasible(RuntimeError):
+    """The cluster (or this dispatcher's view of it) cannot host the model:
+    no healthy node, or no feasible plan or placement.  The control planes
+    catch exactly this to keep serving or retire a replica."""
+
+
 class Dispatcher:
     def __init__(
         self,
@@ -124,7 +130,7 @@ class Dispatcher:
     def elect_leader(self) -> int:
         healthy = self.visible_healthy_ids()
         if not healthy:
-            raise RuntimeError("no healthy nodes")
+            raise PlacementInfeasible("no healthy nodes")
         self.leader = min(healthy)
         return self.leader
 
@@ -204,7 +210,7 @@ class Dispatcher:
         compression_ratio: float = 1.0,
     ) -> InferencePipeline:
         if not plan.feasible:
-            raise RuntimeError("cannot deploy infeasible plan")
+            raise PlacementInfeasible("cannot deploy infeasible plan")
         pods = [
             Pod(f"inf-{plan.version}-{i}", node, part, plan.version)
             for i, (node, part) in enumerate(zip(plan.placement.path, plan.partition.partitions))
@@ -319,7 +325,7 @@ class Dispatcher:
             plan = self.configure(graph, version, capacity=capacity,
                                   compression_ratio=pipeline.compression_ratio)
             if not plan.feasible:
-                raise RuntimeError("cluster too degraded to host the model")
+                raise PlacementInfeasible("cluster too degraded to host the model")
             return self.deploy(plan, pipeline.executor,
                                compression_ratio=pipeline.compression_ratio)
         for pod, node in zip(pipeline.pods, place.path):

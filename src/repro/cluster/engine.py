@@ -47,6 +47,7 @@ from typing import Any
 import jax.numpy as jnp
 
 from repro.cluster.controlplane import ControlPlane, ReconcileAction, ReplicaSet
+from repro.cluster.dispatcher import PlacementInfeasible
 from repro.cluster.events import NodeFailed
 from repro.cluster.lifecycle import Pod
 from repro.cluster.serving import Request, latency_report, normalize_metrics
@@ -1089,7 +1090,7 @@ class ReplicatedServingLoop:
             r = min(active, key=lambda i: (self.loops[i].clock_s, i))
             try:
                 self.completed.extend(self.loops[r].step())
-            except RuntimeError as e:
+            except PlacementInfeasible as e:
                 rset.mark_retired(r, str(e))
                 self._reclaim(r)
             rset.advance_rollout()

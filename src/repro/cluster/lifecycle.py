@@ -120,6 +120,13 @@ class Pod:
 ExecutorFn = Callable[[int, int, Any], Any]  # (start_layer, stop_layer, x) -> y
 
 
+class PipelineDegraded(RuntimeError):
+    """A pod of the pipeline is dead or sits on a failed node: reconcile,
+    then retry.  Serving loops catch exactly this; an error raised by a
+    stage executor (a device fault, say) is not a pod failure and
+    propagates."""
+
+
 @dataclasses.dataclass
 class StepTrace:
     compute_s: list[float]
@@ -202,7 +209,7 @@ class InferencePipeline:
     def run(self, x: Any) -> tuple[Any, StepTrace]:
         """One inference through the chain; raises if a pod is dead."""
         if not self.healthy():
-            raise RuntimeError("pipeline degraded: dead pod or failed node")
+            raise PipelineDegraded("pipeline degraded: dead pod or failed node")
         compute_s, link_s = [], []
         for idx, pod in enumerate(self.pods):
             x = self.executor(pod.partition.start, pod.partition.stop, x)

@@ -38,6 +38,7 @@ from typing import Any
 import jax.numpy as jnp
 
 from repro.cluster.controlplane import ControlPlane, ReconcileAction
+from repro.cluster.lifecycle import PipelineDegraded
 from repro.obs.stats import latency_report, latency_stats, percentile  # noqa: F401 -- re-exported; the single nearest-rank implementation lives in obs.stats
 from repro.obs.trace import split_hop, split_window
 
@@ -167,7 +168,7 @@ class ServingLoop:
         xs = jnp.stack([r.x for r in batch])
         try:
             ys, trace = self.control.pipeline.run(xs)
-        except RuntimeError:
+        except PipelineDegraded:
             self._requeue(batch)
             self._reconcile()
             return []

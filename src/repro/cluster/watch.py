@@ -17,7 +17,7 @@ from __future__ import annotations
 
 from typing import TYPE_CHECKING, Callable
 
-from repro.cluster.dispatcher import Dispatcher
+from repro.cluster.dispatcher import Dispatcher, PlacementInfeasible
 from repro.cluster.events import VersionBumped
 from repro.cluster.lifecycle import InferencePipeline
 from repro.cluster.store import ArtifactStore
@@ -58,7 +58,7 @@ class ModelWatcher:
         graph = self.graph_for_version(latest)
         plan = self.dispatcher.configure(graph, latest)
         if not plan.feasible:
-            raise RuntimeError(f"version {latest} does not fit the cluster")
+            raise PlacementInfeasible(f"version {latest} does not fit the cluster")
         new_pipe = self.dispatcher.deploy(plan, executor, **deploy_kw)
         self.deployed_version = latest
         return new_pipe

@@ -171,9 +171,7 @@ def demo_mlp(d: int = 32, n_layers: int = 8):
     changes the served function.  jax is imported lazily to keep the CNN
     zoo importable without it.
     """
-    import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from repro.core.graph import chain
     from repro.runtime.pipeline import make_layer_executor
@@ -183,14 +181,23 @@ def demo_mlp(d: int = 32, n_layers: int = 8):
     )
 
     def executor_for_version(version: int):
-        ws = np.asarray(
-            jax.random.normal(jax.random.PRNGKey(version), (n_layers, d, d)) * 0.3
-        )
+        ws = demo_mlp_weights(version, d, n_layers)
         return make_layer_executor(
             [lambda x, w=ws[i]: jnp.tanh(x @ w) for i in range(n_layers)]
         )
 
     return graph, executor_for_version
+
+
+def demo_mlp_weights(version: int, d: int = 32, n_layers: int = 8):
+    """``demo_mlp``'s weights at ``version``: (n_layers, d, d) float32 numpy,
+    layer i computing ``tanh(x @ w[i])``."""
+    import jax
+    import numpy as np
+
+    return np.asarray(
+        jax.random.normal(jax.random.PRNGKey(version), (n_layers, d, d)) * 0.3
+    )
 
 
 def demo_ssm(d: int = 24, n_layers: int = 6, seq: int = 8, heads: int = 2,
@@ -341,6 +348,9 @@ def demo_transformer(d: int = 32, n_layers: int = 4, seq: int = 256,
             def fused_int8(enc):
                 # enc: dataplane EncodedActivation with an Int8Codec payload
                 if enc.payload[0] != "jax":
+                    if use_pallas:  # a host payload never reaches the kernel
+                        raise TypeError("fused int8 stage on the Pallas path "
+                                        "got a numpy wire payload")
                     return layer_fn(enc.decode())
                 _, q, s, _dtype = enc.payload
                 qkv = dequant_matmul(

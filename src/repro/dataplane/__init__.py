@@ -12,7 +12,8 @@ restores throughput.  This package is that lever as a subsystem:
     ``wire_bytes`` ratio the byte-counted simulator charges, and an
     encode/decode compute-cost model;
   * ``codecs``   -- ``identity`` / ``fp16`` / ``int8`` (backed by the
-    ``kernels/quantize`` Pallas stack, numpy fallback) / ``topk-sparse``;
+    ``kernels/quantize`` Pallas stack, numpy twin off the Pallas path) /
+    ``topk-sparse``;
   * ``auto``     -- per-link codec selection under a per-link
     ``accuracy_tolerance``, used by the planner's joint codec x placement
     search and provably never worse than ``identity``.

@@ -10,14 +10,14 @@ the papers); these are the TPU-native analogues, each registered by name so
   identity       1.000     0 (lossless)  raw f32 bytes (the historical wire)
   fp16           0.500     2^-11         float16 truncation
   int8           0.254     1/254         blockwise int8 (``kernels/quantize``:
-                                         the Pallas kernel on TPU, its jnp ref
-                                         under jit elsewhere, numpy fallback)
+                                         the Pallas kernel when the knob says
+                                         so, else its jnp ref or numpy twin)
   topk-sparse    0.500     1 (unbounded) top-25% magnitudes as (index, value)
   =============  ========  ============  =======================================
 
 Ratios are for f32 activations.  Transforms accept jax *or* numpy arrays and
-return the same kind -- the engine feeds jax microbatches, unit tests and
-the numpy fallback path feed numpy.
+return the same kind -- the engine feeds jax microbatches, unit tests feed
+numpy.  An int8 codec configured for the Pallas kernel takes jax arrays only.
 """
 
 from __future__ import annotations
@@ -30,17 +30,7 @@ import numpy as np
 from repro.dataplane.base import Codec, _itemsize
 from repro.dataplane.registry import register_codec
 
-try:  # the int8 transform rides the quantize kernel stack when jax is up
-    from repro.kernels.quantize import (
-        INT8_MAX_REL_ERROR,
-        dequantize_int8,
-        quantize_int8,
-    )
-
-    _HAVE_JAX_QUANTIZE = True
-except Exception:  # pragma: no cover - bare-numpy environments
-    INT8_MAX_REL_ERROR = 0.5 / 127.0
-    _HAVE_JAX_QUANTIZE = False
+from repro.kernels.quantize import INT8_MAX_REL_ERROR, dequantize_int8, quantize_int8
 
 
 def _is_jax(x: Any) -> bool:
@@ -112,10 +102,14 @@ class Int8Codec(Codec):
     interpret = False
 
     def encode(self, x):
-        if _HAVE_JAX_QUANTIZE and _is_jax(x):
+        if _is_jax(x):
             q, s = quantize_int8(x, block=self.block, use_pallas=self.use_pallas,
                                  interpret=self.interpret)
             return "jax", q, s, x.dtype
+        if self.use_pallas:
+            # the numpy twin would run on the host, out of the kernel's sight
+            raise TypeError(f"int8 codec on the Pallas path needs a jax array, "
+                            f"got {type(x).__name__}")
         x = np.asarray(x)
         q, s = _np_quantize(x, self.block)
         return "np", q, s, x.dtype

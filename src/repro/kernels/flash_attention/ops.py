@@ -442,22 +442,26 @@ def flash_attention(
 ) -> jax.Array:
     """Blockwise attention; falls back to the naive ref at tiny shapes.
 
-    ``use_pallas=True`` dispatches the forward pass to the Pallas TPU kernel
-    (``interpret=True`` runs it on CPU for CI) when the sequence lengths
-    divide the block size; it is forward-only, which is what the serving
-    executors need.  Shapes the kernel can't tile -- or any gradient use --
-    take the jnp blockwise path below, which has a custom VJP."""
+    ``use_pallas=True`` runs the forward pass on the Pallas TPU kernel
+    (``interpret=True`` runs it on CPU for CI); it is forward-only, which is
+    what the serving executors need, and raises ``ValueError`` on shapes the
+    kernel cannot tile rather than quietly computing elsewhere.  Without it
+    the jnp blockwise path below runs, which has a custom VJP."""
     b, sq, h, hd = q.shape
     skv, kh = k.shape[1], k.shape[2]
     if use_pallas:
         bq, bk = min(block, sq), min(block, skv)
         # self-attention only: the TPU kernel's grid pairs q/kv blocks by
-        # index, so cross-length (sq != skv) shapes take the jnp path
-        if sq == skv and sq % bq == 0 and skv % bk == 0:
-            return flash_attention_tpu(
-                q, k, v, causal=causal, window=window, softcap=softcap,
-                block_q=bq, block_k=bk, interpret=interpret,
-            )
+        # index, so cross-length (sq != skv) shapes cannot run on it
+        if sq != skv or sq % bq or skv % bk:
+            raise ValueError(
+                f"the Pallas flash kernel needs self-attention with the "
+                f"sequence a multiple of the block; got sq={sq}, skv={skv}, "
+                f"block={block}")
+        return flash_attention_tpu(
+            q, k, v, causal=causal, window=window, softcap=softcap,
+            block_q=bq, block_k=bk, interpret=interpret,
+        )
     c = _block_for(sq, skv, block, causal and window == 0)
     if c is None or sq < 2 * 128:
         return attention_ref(q, k, v, causal=causal, window=window, softcap=softcap)

@@ -29,14 +29,16 @@ def _quant_kernel(x_ref, q_ref, s_ref, *, block: int):
 
 def _dequant_kernel(q_ref, s_ref, x_ref, *, block: int):
     rows, d = q_ref.shape
-    qb = q_ref[...].reshape(rows, d // block, block).astype(jnp.float32)
+    # widen before splitting the lanes into blocks: Mosaic cannot reshape an
+    # int8 vector whose block is narrower than a lane tile
+    qb = q_ref[...].astype(jnp.float32).reshape(rows, d // block, block)
     x = qb * s_ref[...][..., None]
     x_ref[...] = x.reshape(rows, d).astype(x_ref.dtype)
 
 
 def _dqmm_kernel(q_ref, s_ref, w_ref, o_ref, *, block: int):
     rows, d = q_ref.shape
-    qb = q_ref[...].reshape(rows, d // block, block).astype(jnp.float32)
+    qb = q_ref[...].astype(jnp.float32).reshape(rows, d // block, block)
     x = (qb * s_ref[...][..., None]).reshape(rows, d)
     o_ref[...] = jax.lax.dot_general(
         x, w_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),

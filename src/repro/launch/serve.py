@@ -33,6 +33,7 @@ from repro.cluster import NodeFailed
 from repro.dataplane import list_codecs
 from repro.workload import list_traces
 from repro.configs import ARCHS, get_config, reduced
+from repro.launch.compile_cache import configure_compile_cache
 from repro.core.model_zoo import demo_mlp, demo_ssm, demo_transformer
 from repro.models import lm
 from repro.runtime.serve import make_serve_step
@@ -218,7 +219,7 @@ def serve_edge(
                 json.dump(d.chrome_trace(), fh)
             print(f"chrome trace written to {trace_out} "
                   f"(load in chrome://tracing or ui.perfetto.dev)")
-    return 0
+    return 1 if s["failed"] else 0
 
 
 def _tenant_input(model: str):
@@ -384,6 +385,7 @@ def main() -> int:
                          "here (requires --trace-sample)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.edge and args.tenants:
         models = [m.strip() for m in args.tenants.split(",") if m.strip()]

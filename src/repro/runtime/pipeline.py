@@ -24,7 +24,6 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.bottleneck import evaluate_pipeline
@@ -122,8 +121,8 @@ def make_gpipe(
 
     ``stage_params`` leaves have a leading ``n_stages`` dim in MESH order
     (sharded over ``axis``; use ``reorder_stage_params`` to realize a SEIFER
-    placement); ``x`` is replicated; output is (n_stages, n_micro, ...) --
-    the last LOGICAL stage's rows are the pipeline output.
+    placement); ``x`` is replicated; the output (n_micro, ...) is the last
+    LOGICAL stage's rows, replicated on every device of ``axis``.
 
     ``stage_order[j]`` = mesh position hosting logical stage j; the
     ppermute route follows it, so the heaviest boundary rides the link the
@@ -177,19 +176,15 @@ def make_gpipe(
         (buf, outs), _ = jax.lax.scan(
             tick, (buf, outs), jnp.arange(n_micro + n_stages - 1)
         )
-        return outs
+        # only the last logical stage ever writes ``outs`` (the others keep
+        # zeros), so the sum over the stage axis is exact and leaves the
+        # rows replicated -- valid under Auto and Explicit mesh axes alike
+        return jax.lax.psum(outs, axis)
 
-    in_specs = (P(axis), P())
-    out_specs = P(axis)  # concatenates stage rows along dim 0
-    sm = shard_map(pipe, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-    def run(stage_params, x):
-        out = sm(stage_params, x)
-        out = out.reshape((n_stages,) + x.shape)  # (stage, n_micro, mb...)
-        return out[order[-1]]  # rows of the last LOGICAL stage
-
-    return run
+    # the scan carry turns stage-varying after the first tick, so the
+    # varying-manual-axes check cannot type it
+    return jax.shard_map(pipe, mesh=mesh, in_specs=(P(axis), P()),
+                         out_specs=P(), check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -33,6 +33,7 @@ from repro.api.spec import (
     as_tenants,
     validate_tenants,
 )
+from repro.cluster.dispatcher import PlacementInfeasible
 from repro.cluster.events import ClusterEvent
 from repro.cluster.lifecycle import EdgeCluster
 from repro.cluster.serving import Request
@@ -97,7 +98,7 @@ def deploy_tenants(
                 seed_offset=_TENANT_SEED_STRIDE * idx,
                 journal=journal, source_prefix=f"{tenant.name}/",
             )
-        except (InfeasibleSpecError, RuntimeError) as e:
+        except (InfeasibleSpecError, PlacementInfeasible) as e:
             detail = ("; ".join(i.message for i in e.issues)
                       if isinstance(e, InfeasibleSpecError) else str(e))
             raise InfeasibleSpecError((SpecIssue(

@@ -110,6 +110,18 @@ def test_roundtrip_error_within_reported_bound(asarray):
         assert err <= codec.error_bound * (1 + 1e-4) + 1e-9, name
 
 
+def test_int8_on_the_pallas_path_refuses_host_arrays():
+    """Configured for the Pallas kernel, the int8 codec quantizes jax arrays
+    through it and refuses numpy input instead of quantizing on the host."""
+    codec = get_codec("int8").configured(use_pallas=True, interpret=True)
+    x = np.random.default_rng(0).normal(size=(4, 256)).astype(np.float32)
+    with pytest.raises(TypeError, match="jax array"):
+        codec.encode(x)
+    y = codec.transcode(jnp.asarray(x))
+    assert float(np.max(np.abs(np.asarray(y) - x))) <= (
+        codec.error_bound * float(np.max(np.abs(x))) * (1 + 1e-4))
+
+
 def test_identity_is_exact_and_free():
     codec = get_codec("identity")
     x = jnp.ones((3, 5))

@@ -72,13 +72,17 @@ def test_flash_grads_match_ref(case, layout):
 def test_flash_use_pallas_dispatch_matches_ref(case):
     """The ops-level ``use_pallas`` knob (interpret mode) stays within the
     documented forward tolerance vs attention_ref.  Cross-length shapes
-    (sq != skv) silently take the jnp path -- the result must be equally
-    correct either way, which is exactly what serving executors rely on."""
+    (sq != skv) do not fit the kernel's grid: they raise instead of quietly
+    taking the jnp path, so a served result always came from the kernel."""
     b, sq, skv, h, kh, hd, causal, window, softcap, block, dtype = case
     q, k, v = _qkv(b, sq, skv, h, kh, hd, dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          softcap=softcap, block=block,
-                          use_pallas=True, interpret=True)
+    kw = dict(causal=causal, window=window, softcap=softcap, block=block,
+              use_pallas=True, interpret=True)
+    if sq != skv:
+        with pytest.raises(ValueError, match="self-attention"):
+            flash_attention(q, k, v, **kw)
+        return
+    out = flash_attention(q, k, v, **kw)
     ref = attention_ref(q, k, v, causal=causal, window=window, softcap=softcap)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),

@@ -1,6 +1,7 @@
 """GPipe pipeline correctness on a faked 4-device host (subprocess, so the
 main test process keeps its single-device view)."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -79,7 +80,9 @@ def test_gpipe_four_stages():
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": str(repo / "src"), "PATH": "/usr/bin:/bin"},
+        # the child stays on the CPU: it must never load the TPU library
+        env={**os.environ, "PYTHONPATH": str(repo / "src"),
+             "JAX_PLATFORMS": "cpu"},
         cwd=repo,
     )
     assert "PIPELINE_OK" in proc.stdout, proc.stdout + proc.stderr

@@ -1,0 +1,95 @@
+"""The main path's Pallas kernels compile for a TPU v5e chip.
+
+Interpret mode cannot see what the TPU compiler refuses (block shapes that
+do not tile, vector shape casts Mosaic does not support), so each kernel on
+the served path is compiled here for a described -- not attached --
+``v5e:2x2`` chip at the shapes the demo models and the GPipe boundary use,
+and the compiled program must contain the kernel (``tpu_custom_call``).
+Nothing runs; no chip is needed.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention.kernel import flash_attention_tpu
+from repro.kernels.quantize.kernel import (
+    dequant_matmul_tpu,
+    dequantize_int8_tpu,
+    quantize_int8_tpu,
+)
+from repro.kernels.ssm_scan.kernel import ssd_chunked_tpu
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One described v5e chip, with the persistent compilation cache off
+    (a compile for a described chip is written but cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else libtpu logs outside the checkout
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+F32, I8 = jnp.float32, jnp.int8
+# demo_transformer: (batch, seq 256, d 32), 4 query / 2 kv heads of dim 8;
+# demo_ssm: (batch, seq 8, d 24), 2 heads of dim 12, state 4
+CASES = {
+    "flash_window0": (
+        lambda q, k, v: flash_attention_tpu(
+            q, k, v, causal=True, window=0, softcap=50.0,
+            block_q=128, block_k=128),
+        [((4, 256, 4, 8), F32), ((4, 256, 2, 8), F32), ((4, 256, 2, 8), F32)]),
+    "flash_window128": (
+        lambda q, k, v: flash_attention_tpu(
+            q, k, v, causal=True, window=128, softcap=50.0,
+            block_q=128, block_k=128),
+        [((4, 256, 4, 8), F32), ((4, 256, 2, 8), F32), ((4, 256, 2, 8), F32)]),
+    "quantize_transformer": (
+        lambda x: quantize_int8_tpu(x, 256), [((4, 256, 32), F32)]),
+    "quantize_ssm": (
+        lambda x: quantize_int8_tpu(x, 256), [((3, 8, 24), F32)]),
+    "dequantize_ssm": (
+        lambda q, s: dequantize_int8_tpu(q, s, dtype=F32, block=256),
+        [((3, 8, 24), I8), ((3, 8, 1), F32)]),
+    "dequantize_gpipe_block32": (
+        lambda q, s: dequantize_int8_tpu(q, s, dtype=F32, block=32),
+        [((16, 32), I8), ((16, 1), F32)]),
+    "dequant_matmul_transformer": (
+        lambda q, s, w: dequant_matmul_tpu(q, s, w, dtype=F32, block=256),
+        [((4, 256, 32), I8), ((4, 256, 1), F32), ((32, 64), F32)]),
+    "dequant_matmul_block32": (
+        lambda q, s, w: dequant_matmul_tpu(q, s, w, dtype=F32, block=32),
+        [((16, 32), I8), ((16, 1), F32), ((32, 32), F32)]),
+    "ssd_demo_ssm": (
+        lambda xs, bm, cm, dt, a: ssd_chunked_tpu(xs, bm, cm, dt, a, chunk=8),
+        [((4, 8, 2, 12), F32), ((4, 8, 4), F32), ((4, 8, 4), F32),
+         ((4, 8, 2), F32), ((2,), F32)]),
+    "ssd_chunk128": (
+        lambda xs, bm, cm, dt, a: ssd_chunked_tpu(xs, bm, cm, dt, a, chunk=128),
+        [((1, 512, 8, 64), F32), ((1, 512, 64), F32), ((1, 512, 64), F32),
+         ((1, 512, 8), F32), ((8,), F32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip):
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1, name
