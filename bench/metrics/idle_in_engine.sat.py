@@ -1,0 +1,11 @@
+"""Share of the traced window, in percent, in which chip 0 was idle while the
+host was in the serving engine's own code (``seifer.step``, ``.admit``,
+``.complete`` or ``.reconcile`` the innermost open program span)."""
+
+from bench import spans
+
+RESULTS = spans.results_dir(__file__)
+
+
+def read(run):
+    return spans.idle_share(run.device, spans.program_spans(run, RESULTS), spans.ENGINE)

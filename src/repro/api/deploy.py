@@ -38,7 +38,13 @@ from repro.cluster.lifecycle import EdgeCluster
 from repro.cluster.serving import Request, ServingLoop
 from repro.cluster.store import ArtifactStore
 from repro.cluster.watch import ModelWatcher
-from repro.obs import Journal, MetricsRegistry, SpanTracer, analyze_spans
+from repro.obs import (
+    Journal,
+    MetricsRegistry,
+    SpanTracer,
+    analyze_spans,
+    install_gc_span,
+)
 
 
 def _passthrough_executor(start: int, stop: int, x):
@@ -297,6 +303,7 @@ class Deployment:
         self.tracer = (
             SpanTracer(spec.trace) if spec.trace is not None else None)
         self.registry = MetricsRegistry()
+        install_gc_span()  # seifer.gc spans inside a profiler session
         if replicaset is not None:
             # replica 0 as the representative for shared resources
             # (cluster/store are one object across every replica)
@@ -590,10 +597,9 @@ class Deployment:
         })
 
     def _finalize_metrics(self, out: dict) -> dict:
-        """Mirror the payload into the metrics registry, then attach the
-        registry snapshot + trace digest (additive keys: everything the
-        payload held before observability landed is untouched)."""
-        self.registry.ingest("deployment", out)
+        """Attach the registry snapshot + trace digest (additive keys:
+        everything the payload held before observability landed is
+        untouched)."""
         out["observability"] = {
             "metrics": self.registry.snapshot(),
             "trace": (self.tracer.summary()

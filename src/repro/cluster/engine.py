@@ -31,6 +31,12 @@ independently:
     count; batches elsewhere keep their partial progress, because the
     re-placement recovery path preserves partitions.
 
+Besides the virtual clock, ``PipelinedServingLoop`` opens wall-clock
+``jax.profiler.TraceAnnotation`` spans (``seifer.step``, ``seifer.stage``,
+``seifer.codec`` ...; see ``repro.obs.profiler``) around its host work, so a
+profiler capture shows what the host did next to the device's ops.  They
+record only inside a profiler session and never wait on the device.
+
 The engine exposes the same surface as ``ServingLoop`` (``submit`` /
 ``step`` / ``drain`` / ``metrics`` / ``backlog``), so ``Deployment`` and the
 benchmarks can switch between the honest synchronous baseline and the
@@ -45,6 +51,7 @@ from collections import deque
 from typing import Any
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.cluster.controlplane import ControlPlane, ReconcileAction, ReplicaSet
 from repro.cluster.dispatcher import PlacementInfeasible
@@ -260,31 +267,37 @@ class PipelinedServingLoop:
         the health check) are reconciled first, requeueing exactly the
         in-flight microbatches resident on affected stages.
         """
-        done0 = len(self.completed)
-        pipe = self.control.pipeline
-        if pipe is None:
-            raise RuntimeError("bootstrap the control plane before serving")
-        if pipe is not self._bound_pipeline:
-            # out-of-band swap (e.g. Deployment.replan): nothing carries over
-            self._rebind(affected=_ALL)
-        elif self._pod_signature() != self._pod_sig:
-            # out-of-band in-place recovery (reconcile() called directly, not
-            # through step): restarted pods lost their resident batches, moved
-            # pods migrated with theirs; timings re-derive either way
-            restarted = {
-                s for s, (pod, (_, _, restarts0)) in
-                enumerate(zip(pipe.pods, self._pod_sig))
-                if pod.restarts != restarts0
-            }
-            self._rebind(affected=frozenset(restarted))
-        if self.control.pending or not pipe.healthy():
-            self._reconcile()
-        self._admit_due()
-        self._schedule()
-        while len(self.completed) == done0:
-            if not self._advance():
-                break
-        return self.completed[done0:]
+        with TraceAnnotation("seifer.step"):
+            done0 = len(self.completed)
+            pipe = self.control.pipeline
+            if pipe is None:
+                raise RuntimeError("bootstrap the control plane before serving")
+            affected = None
+            if pipe is not self._bound_pipeline:
+                # out-of-band swap (e.g. Deployment.replan): nothing carries over
+                affected = _ALL
+            elif self._pod_signature() != self._pod_sig:
+                # out-of-band in-place recovery (reconcile() called directly,
+                # not through step): restarted pods lost their resident
+                # batches, moved pods migrated with theirs; timings re-derive
+                # either way
+                affected = frozenset(
+                    s for s, (pod, (_, _, restarts0)) in
+                    enumerate(zip(pipe.pods, self._pod_sig))
+                    if pod.restarts != restarts0
+                )
+            if affected is not None:
+                with TraceAnnotation("seifer.reconcile", kind="rebind"):
+                    self._rebind(affected=affected)
+            if self.control.pending or not pipe.healthy():
+                with TraceAnnotation("seifer.reconcile", kind="reconcile"):
+                    self._reconcile()
+            self._admit_due()
+            self._schedule()
+            while len(self.completed) == done0:
+                if not self._advance():
+                    break
+            return self.completed[done0:]
 
     def drain(self, max_rounds: int = 100_000) -> list[Request]:
         """Step until every admitted request completes (or max_rounds).
@@ -632,7 +645,10 @@ class PipelinedServingLoop:
             if kind == "compute":
                 st = self._stages[idx]
                 part = st.pod.partition
-                mb.x = self.control.pipeline.executor(part.start, part.stop, mb.x)
+                with TraceAnnotation("seifer.stage", stage=idx, first=part.start,
+                                     stop=part.stop, batch=len(mb.requests)):
+                    mb.x = self.control.pipeline.executor(
+                        part.start, part.stop, mb.x)
                 st.busy_s += st.compute_s
                 st.completed += 1
                 st.current = None
@@ -651,20 +667,26 @@ class PipelinedServingLoop:
                 codec = self._link_codecs[idx] if idx < len(self._link_codecs) else None
                 if codec is not None:
                     executor = self.control.pipeline.executor
-                    if (idx != k and codec.name
-                            in getattr(executor, "fused_codecs", ())):
-                        # fused decode: the receiving stage's first op
-                        # consumes the wire payload directly (e.g. int8 ->
-                        # dequant-matmul), so hand over the still-encoded
-                        # activation instead of eagerly decoding it
-                        from repro.dataplane.base import EncodedActivation
+                    fused = (idx != k and codec.name
+                             in getattr(executor, "fused_codecs", ()))
+                    with TraceAnnotation(
+                            "seifer.codec", hop=idx, codec=codec.name,
+                            op="encode" if fused else "transcode"):
+                        if fused:
+                            # fused decode: the receiving stage's first op
+                            # consumes the wire payload directly (e.g. int8
+                            # -> dequant-matmul), so hand over the
+                            # still-encoded activation instead of eagerly
+                            # decoding it
+                            from repro.dataplane.base import EncodedActivation
 
-                        mb.x = EncodedActivation(codec, codec.encode(mb.x))
-                    else:
-                        # the receiver sees decode(encode(x)): the codec's
-                        # real transform (Pallas int8 stack, fp16, top-k)
-                        # runs on the activations riding the wire
-                        mb.x = codec.transcode(mb.x)
+                            mb.x = EncodedActivation(codec, codec.encode(mb.x))
+                        else:
+                            # the receiver sees decode(encode(x)): the
+                            # codec's real transform (Pallas int8 stack,
+                            # fp16, top-k) runs on the activations riding
+                            # the wire
+                            mb.x = codec.transcode(mb.x)
                 if idx == k:
                     self._complete(mb)
                 else:
@@ -729,11 +751,12 @@ class PipelinedServingLoop:
             ):
                 cap = self.max_batch if self.max_batch is not None else self.microbatch
                 take = min(cap, len(self.queue))
-                batch = self._take_batch(take)
+                with TraceAnnotation("seifer.admit", batch=take):
+                    batch = self._take_batch(take)
+                    x = jnp.stack([r.x for r in batch])
                 self._max_batch_seen = max(self._max_batch_seen, len(batch))
                 mb = Microbatch(
-                    self._next_mb, batch,
-                    jnp.stack([r.x for r in batch]),
+                    self._next_mb, batch, x,
                     stage=0, location=("link", 0),
                     ready_at=self.clock_s + self._link_s[0],
                 )
@@ -810,21 +833,22 @@ class PipelinedServingLoop:
             self._readmit(mb.requests, retry=True)
 
     def _complete(self, mb: Microbatch) -> None:
-        self._inflight.remove(mb)
-        self._mb_completed += 1
-        reg = self._registry
-        if reg is not None:
-            reg.counter("requests_completed", engine="pipelined").inc(
-                len(mb.requests))
-            reg.counter("microbatches_completed", engine="pipelined").inc()
-        for i, req in enumerate(mb.requests):
-            req.result = mb.x[i]
-            req.completed_s = self.clock_s
-            self.completed.append(req)
+        with TraceAnnotation("seifer.complete", batch=len(mb.requests)):
+            self._inflight.remove(mb)
+            self._mb_completed += 1
+            reg = self._registry
             if reg is not None:
-                reg.histogram(
-                    "request_latency_s", engine="pipelined",
-                ).observe(req.latency_s)
+                reg.counter("requests_completed", engine="pipelined").inc(
+                    len(mb.requests))
+                reg.counter("microbatches_completed", engine="pipelined").inc()
+            for i, req in enumerate(mb.requests):
+                req.result = mb.x[i]
+                req.completed_s = self.clock_s
+                self.completed.append(req)
+                if reg is not None:
+                    reg.histogram(
+                        "request_latency_s", engine="pipelined",
+                    ).observe(req.latency_s)
 
     # -- span tracing ----------------------------------------------------------
     # A microbatch carries at most one OPEN phase (``mb.phase``): the

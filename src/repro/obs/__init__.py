@@ -13,6 +13,9 @@ One package owns every "what happened and where did the time go" question:
   exported as one schema-validated snapshot.
 * ``obs.stats`` -- the single nearest-rank percentile + latency report
   implementation (serving, tenancy, and the autoscaler all route here).
+* ``obs.profiler`` -- the one part on the wall clock: the ``seifer.gc``
+  hook beside the engine's ``seifer.*`` ``jax.profiler.TraceAnnotation``
+  spans, which land next to the device's ops in a profiler capture.
 * ``obs.critical_path`` -- folds span timelines into per-request and
   aggregate latency attributions (queue/compute/wire/transcode) and pins
   observed per-stage service times against the plan's
@@ -25,6 +28,7 @@ it sits below them so every layer can depend on it without cycles.
 from repro.obs.critical_path import analyze_spans, request_attribution
 from repro.obs.journal import Journal, JournalRecord
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.profiler import install_gc_span
 from repro.obs.stats import latency_report, latency_stats, percentile
 from repro.obs.trace import Span, SpanTracer, TraceConfig
 
@@ -36,6 +40,7 @@ __all__ = [
     "SpanTracer",
     "TraceConfig",
     "analyze_spans",
+    "install_gc_span",
     "latency_report",
     "latency_stats",
     "percentile",

@@ -3,10 +3,8 @@
 Each deployment owns one :class:`MetricsRegistry`.  Components update
 instruments directly on the hot path (engines count
 completions/failures/requeues and observe latencies), and
-``Deployment.metrics()`` additionally mirrors its assembled JSON payload
-into gauges via :meth:`MetricsRegistry.ingest` -- so
-:meth:`MetricsRegistry.snapshot` is the one schema-validated superset
-view while the legacy payload shape stays byte-identical on top of it.
+``Deployment.metrics()`` attaches :meth:`MetricsRegistry.snapshot`, one
+schema-validated view of them, beside its JSON payload.
 
 Everything recorded here must be a finite native number derived from the
 virtual clock / request counts -- :meth:`snapshot` validates this, so a
@@ -109,30 +107,6 @@ class MetricsRegistry:
         if key not in self._histograms:
             self._histograms[key] = Histogram(name, labels)
         return self._histograms[key]
-
-    # -- payload mirroring -------------------------------------------------
-    def ingest(self, prefix: str, payload) -> None:
-        """Mirror every numeric leaf of a metrics payload into gauges.
-
-        The gauge name is the dotted path (list indices become an ``i``
-        label component), so the registry snapshot subsumes the legacy
-        ``metrics()`` dict without changing its shape.
-        """
-        def walk(value, path):
-            if isinstance(value, dict):
-                for k, v in value.items():
-                    walk(v, f"{path}.{k}")
-            elif isinstance(value, (list, tuple)):
-                for i, v in enumerate(value):
-                    walk(v, f"{path}[{i}]")
-            elif isinstance(value, bool) or value is None or isinstance(value, str):
-                return
-            elif isinstance(value, (int, float)):
-                if isinstance(value, float) and not math.isfinite(value):
-                    return
-                self.gauge(path).set(value)
-
-        walk(payload, prefix)
 
     # -- export ------------------------------------------------------------
     def snapshot(self) -> dict:

@@ -10,7 +10,10 @@ Covers the four components end to end on real deployments:
     that agree with ``Dispatcher.last_recovery``;
   * metrics registry -- schema-valid snapshots embedded in
     ``Deployment.metrics()`` without disturbing the legacy shape;
-  * critical-path analyzer -- fractions sum to one, bottleneck agreement.
+  * critical-path analyzer -- fractions sum to one, bottleneck agreement;
+  * wall-clock spans (``seifer.*``) in a profiler capture on the CPU --
+    one per stage call and coded hop, with their metadata, and none
+    without a session.
 
 Determinism is pinned hard: same-seed runs must serialize byte-identically
 (timelines, Chrome traces, and journal dumps).
@@ -355,3 +358,109 @@ def test_percentile_has_one_nearest_rank_implementation():
     assert percentile(vals, 0.50) == 50.0
     assert percentile(vals, 0.99) == 99.0
     assert percentile(vals, 1.00) == 100.0
+
+
+# -- wall-clock spans on the profiler's clock ---------------------------------
+
+def _seifer_events(trace_dir) -> list[dict]:
+    """Every ``seifer.*`` host event of a profiler capture, in time order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("seifer."):
+                    out.append({"name": e.name, "t0": e.start_ns, "t1": e.end_ns,
+                                "thread": line.name, "args": dict(e.stats)})
+    return sorted(out, key=lambda e: (e["t0"], -e["t1"]))
+
+
+BATCHES = 3  # of the default microbatch, 4
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """Three batches of four requests served with int8 hops under a
+    profiler session on the CPU."""
+    import jax
+
+    d = _deploy(sample=None, codec="int8")
+    trace_dir = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(trace_dir)):
+        _serve(d, 4 * BATCHES)
+    return d, _seifer_events(trace_dir)
+
+
+def _inside(inner, outer) -> bool:
+    return (outer["thread"] == inner["thread"]
+            and outer["t0"] <= inner["t0"] and inner["t1"] <= outer["t1"])
+
+
+def test_profiler_spans_time_each_stage_call_inside_its_step(profiled):
+    d, events = profiled
+    steps = [e for e in events if e["name"] == "seifer.step"]
+    stage_spans = [e for e in events if e["name"] == "seifer.stage"]
+    parts = [p.partition for p in d.control.pipeline.pods]
+    assert len(stage_spans) == len(parts) * BATCHES
+    for e in stage_spans:
+        assert any(_inside(e, s) for s in steps)
+    for s, part in enumerate(parts):
+        mine = [e["args"] for e in stage_spans if e["args"]["stage"] == s]
+        assert mine == [{"stage": s, "first": part.start, "stop": part.stop,
+                         "batch": 4}] * BATCHES
+
+
+def test_profiler_spans_time_each_coded_hop(profiled):
+    d, events = profiled
+    coded = [h for h, c in enumerate(d.loop._link_codecs) if c is not None]
+    assert coded
+    spans = [e["args"] for e in events if e["name"] == "seifer.codec"]
+    assert sorted(a["hop"] for a in spans) == sorted(coded * BATCHES)
+    assert all(a["codec"] == d.loop._link_codecs[a["hop"]].name for a in spans)
+    assert "int8" in {a["codec"] for a in spans}
+    assert {a["op"] for a in spans} <= {"transcode", "encode"}
+
+
+def test_profiler_spans_name_admission_and_completion(profiled):
+    _, events = profiled
+    steps = [e for e in events if e["name"] == "seifer.step"]
+    for name in ("seifer.admit", "seifer.complete"):
+        spans = [e for e in events if e["name"] == name]
+        assert len(spans) == BATCHES, name
+        assert all(e["args"] == {"batch": 4} for e in spans)
+        assert all(any(_inside(e, s) for s in steps) for e in spans)
+
+
+def test_no_profiler_span_is_recorded_without_a_session(tmp_path):
+    import gc
+
+    import jax
+
+    d = _serve(_deploy(sample=None, codec="int8"), 8)  # no session open
+    gc.collect()
+    with jax.profiler.trace(str(tmp_path)):
+        jnp.ones(4).block_until_ready()
+    assert d.loop.completed
+    assert _seifer_events(tmp_path) == []
+
+
+def test_gc_span_records_each_collection_inside_a_session(tmp_path):
+    import gc
+
+    import jax
+
+    from repro.obs import install_gc_span
+    from repro.obs.profiler import _on_gc
+
+    install_gc_span()
+    install_gc_span()
+    assert gc.callbacks.count(_on_gc) == 1
+    with jax.profiler.trace(str(tmp_path)):
+        gc.collect()
+    spans = [e for e in _seifer_events(tmp_path) if e["name"] == "seifer.gc"]
+    assert spans and spans[-1]["args"] == {"generation": 2}
+    assert all(e["t1"] >= e["t0"] for e in spans)
