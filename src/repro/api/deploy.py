@@ -600,6 +600,7 @@ class Deployment:
         """Attach the registry snapshot + trace digest (additive keys:
         everything the payload held before observability landed is
         untouched)."""
+        self._gauge_stage_counts()
         out["observability"] = {
             "metrics": self.registry.snapshot(),
             "trace": (self.tracer.summary()
@@ -608,6 +609,19 @@ class Deployment:
         from repro.cluster.serving import normalize_metrics
 
         return normalize_metrics(out)
+
+    def _gauge_stage_counts(self) -> None:
+        """Gauge the stage executors in service (``make_layer_executor``'s
+        ``counts``, summed over distinct executors): programs traced,
+        compiled calls and eager calls."""
+        controls = (self.replicaset.controls if self.replicaset is not None
+                    else [self.control])
+        executors = {id(e): e for e in (
+            c.pipeline.executor for c in controls if c.pipeline is not None)}
+        counts = [e.counts for e in executors.values() if hasattr(e, "counts")]
+        for name in ("traces", "compiled_calls", "eager_calls"):
+            self.registry.gauge(f"stage_{name}").set(
+                sum(getattr(c, name) for c in counts))
 
     # -- observability --------------------------------------------------------
     def trace_timeline(self) -> list[dict]:

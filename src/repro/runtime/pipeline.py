@@ -191,6 +191,30 @@ def make_gpipe(
 # Edge-cluster bridge: run the same stage execution through simulated pods
 # ---------------------------------------------------------------------------
 
+@dataclasses.dataclass
+class StageCounts:
+    """What a layer executor did: stage programs traced (cache misses),
+    calls served by a compiled program, and calls run layer by layer."""
+
+    traces: int = 0
+    compiled_calls: int = 0
+    eager_calls: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class StageProgram:
+    """One layer range compiled for one input: ``fn`` is the ``jax.jit``
+    of ``seifer_stage``, which evaluates the range's traced jaxpr with its
+    constants (the weights) passed in as ``consts``, never embedded."""
+
+    fn: Callable
+    consts: tuple
+    out_tree: Any
+
+    def __call__(self, x: jax.Array):
+        return jax.tree.unflatten(self.out_tree, self.fn(self.consts, x))
+
+
 def make_layer_executor(layer_fns: list[Callable[[jax.Array], jax.Array]]):
     """Adapt per-layer callables into the cluster ``ExecutorFn`` signature.
 
@@ -200,6 +224,22 @@ def make_layer_executor(layer_fns: list[Callable[[jax.Array], jax.Array]]):
     closures) serve through the simulated pod chain, so the serving loop's
     microbatches exercise identical math on both backends.
 
+    **Compiled stages.**  A ``jax.Array`` input runs the range as ONE
+    compiled program, so XLA fuses the layers' elementwise passes and the
+    call returns once the work is dispatched.  Programs are cached per
+    ``(start, stop, x.shape, x.dtype, weak type)`` and the trace context
+    that changes the traced program (the default matmul precision, x64).
+    On a miss the range is traced once with ``jax.make_jaxpr``; its
+    constants -- the weights the layer fns close over -- are hoisted into
+    arguments of a ``jax.jit`` named ``seifer_stage`` (embedded, JAX would
+    write them into the program as literals).  Device-array constants are
+    passed by reference; host (numpy) constants are uploaded once per
+    executor, memoised by their host buffer.  Host inputs keep the eager
+    layer-by-layer loop, so numpy codecs and host-side callers see what
+    they always did.  ``executor.counts`` (``StageCounts``) counts traces,
+    compiled calls and eager calls; ``executor.program(start, stop, x)``
+    returns the cached ``StageProgram`` (tracing it on a miss).
+
     **Fused decode protocol.**  A layer fn may carry a ``fused`` attribute --
     a ``{codec_name: handler}`` dict whose handler consumes a still-encoded
     boundary activation (``dataplane.base.EncodedActivation``) directly,
@@ -208,12 +248,54 @@ def make_layer_executor(layer_fns: list[Callable[[jax.Array], jax.Array]]):
     ``executor.fused_codecs`` -- codec names EVERY layer can consume, so the
     engine's gating stays correct for any partition cut point -- and
     transparently falls back to ``EncodedActivation.decode()`` when the
-    entry layer has no handler.
+    entry layer has no handler.  The handler (or the decode) runs before,
+    and outside, the compiled program of the remaining layers.
     """
     fused_codecs: frozenset[str] | None = None
     for fn in layer_fns:
         keys = frozenset(getattr(fn, "fused", {}) or {})
         fused_codecs = keys if fused_codecs is None else fused_codecs & keys
+
+    def run_layers(start: int, stop: int, x):
+        for i in range(start, stop):
+            x = layer_fns[i](x)
+        return x
+
+    counts = StageCounts()
+    programs: dict[tuple, StageProgram] = {}
+    uploaded: dict[tuple, tuple[np.ndarray, jax.Array]] = {}
+
+    def device_const(c):
+        if isinstance(c, jax.Array):
+            return c
+        host = np.asarray(c)
+        # the buffer's address names it while ``uploaded`` keeps it alive;
+        # the zoo's ``w[i]`` is a fresh view of one array on every trace
+        key = (host.__array_interface__["data"][0], host.shape, host.strides,
+               host.dtype.str)
+        if key not in uploaded:
+            uploaded[key] = (host, jax.device_put(host))
+        return uploaded[key][1]
+
+    def program(start: int, stop: int, x) -> StageProgram:
+        key = (start, stop, x.shape, x.dtype, jax.typeof(x).weak_type,
+               jax.config.jax_default_matmul_precision,
+               jax.config.jax_enable_x64)
+        prog = programs.get(key)
+        if prog is None:
+            closed, out_shape = jax.make_jaxpr(
+                partial(run_layers, start, stop), return_shape=True)(x)
+            jaxpr = closed.jaxpr
+
+            def seifer_stage(consts, x):
+                return jax.core.eval_jaxpr(jaxpr, consts, x)
+
+            prog = programs[key] = StageProgram(
+                jax.jit(seifer_stage),
+                tuple(device_const(c) for c in closed.consts),
+                jax.tree.structure(out_shape))
+            counts.traces += 1
+        return prog
 
     def executor(start: int, stop: int, x):
         if isinstance(x, EncodedActivation):
@@ -225,11 +307,17 @@ def make_layer_executor(layer_fns: list[Callable[[jax.Array], jax.Array]]):
                 start += 1
             else:
                 x = x.decode()
-        for i in range(start, stop):
-            x = layer_fns[i](x)
-        return x
+        if start >= stop:
+            return x
+        if isinstance(x, jax.Array):
+            counts.compiled_calls += 1
+            return program(start, stop, x)(x)
+        counts.eager_calls += 1
+        return run_layers(start, stop, x)
 
     executor.fused_codecs = fused_codecs or frozenset()
+    executor.counts = counts
+    executor.program = program
     return executor
 
 

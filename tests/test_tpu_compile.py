@@ -93,3 +93,60 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 1, name
+
+
+# a compiled stage of each kernel-backed demo model: the kernels' custom calls
+# survive inside the one program, as the roofline readers match them
+STAGES = {
+    "demo_ssm": ((4, 8, 24), "bench.metrics.ssd_scan_roofline"),
+    "demo_transformer": ((4, 256, 32), "bench.metrics.flash_attn_roofline"),
+}
+
+
+def kernel_calls(text):
+    """(name, output shapes, operand shapes) of each Pallas kernel call in a
+    compiled module's text, operands read from its layout constraints (the
+    profiler's op text, which the readers see, writes them inline)."""
+    import re
+
+    from bench.devtrace import hlo_shapes, is_kernel, op_name
+
+    calls = []
+    for line in text.splitlines():
+        if not is_kernel(line):
+            continue
+        tail = line.split("operand_layout_constraints={", 1)[1]
+        depth = 1
+        for end, ch in enumerate(tail):
+            depth += (ch == "{") - (ch == "}")
+            if depth == 0:
+                break
+        ins = [(dt, tuple(int(d) for d in dims.split(",") if d)) for dt, dims
+               in re.findall(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]", tail[:end])]
+        calls.append((op_name(line), hlo_shapes(line)[0], ins))
+    return calls
+
+
+@pytest.mark.parametrize("model", sorted(STAGES))
+def test_compiled_stage_keeps_its_kernels_for_v5e(model, one_chip):
+    import importlib
+
+    from repro.core import model_zoo
+
+    shape, reader = STAGES[model]
+    match = importlib.import_module(reader)._match
+    graph, efv = getattr(model_zoo, model)(use_pallas=True)
+    n = len(graph.layers)
+    prog = efv(0).program(1, n, jnp.zeros(shape, F32))  # traced on the CPU
+    args = [jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=one_chip)
+            for c in prog.consts]
+    x = jax.ShapeDtypeStruct(shape, F32, sharding=one_chip)
+    calls = kernel_calls(prog.fn.lower(args, x).compile().as_text())
+    found = [name for name, outs, ins in calls if match(name, outs, ins)]
+    assert len(found) == n - 1, (model, calls)
+    if model == "demo_ssm":
+        assert set(found) == {"ssd_chunked"}
+        assert all(len(ins) == 7 for _, _, ins in calls)
+    else:
+        assert all(len(ins) == 3 and all(len(s) == 3 for _, s in ins)
+                   for _, _, ins in calls)
