@@ -47,7 +47,7 @@ class ModelConfig:
     pad_heads_to: int = 0
 
     # --- block internals ---
-    mlp_kind: Literal["swiglu", "geglu", "gelu"] = "swiglu"
+    mlp_kind: Literal["swiglu", "geglu", "gelu", "relu2"] = "swiglu"
     norm_kind: Literal["rmsnorm", "layernorm"] = "rmsnorm"
     gemma_norm: bool = False  # (1 + w) RMSNorm scaling + embed * sqrt(d)
     post_norm: bool = False  # gemma2 post-attn/post-ffn extra norms

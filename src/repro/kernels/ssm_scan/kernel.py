@@ -9,7 +9,9 @@ L-matrix 64 KiB f32, state 16 KiB -- trivially resident.
 
 Layout: the wrapper folds the head into the leading axis, so every block's
 last two dims are either (Q, dh)/(Q, N) or a per-position vector as a
-(Q, 1) column or a (1, Q) row -- the shapes the TPU lowering tiles.  The
+(Q, 1) column or a (1, Q) row -- the shapes the TPU lowering tiles.  B and C
+come in G groups (Mamba2's ``ngroups``) folded the same way, as (B*G, S, N):
+head h of a sequence reads group h // (H/G).  One group keeps them (B, S, N).  The
 per-chunk cumulative decays are precomputed outside (one cumsum) and handed
 in both orientations, so the kernel never transposes a vector and has no
 sequential math inside a chunk.
@@ -71,12 +73,22 @@ def _ssd_kernel(xs_ref, bm_ref, cm_ref, dt_row_ref, cum_row_ref, cum_col_ref,
 
 
 def ssd_chunked_tpu(xs, bm, cm, dt, a, *, chunk: int = 128, interpret: bool = False):
-    """xs (B,S,H,dh), bm/cm (B,S,N), dt (B,S,H), a (H,) -> y (B,S,H,dh) f32."""
+    """xs (B,S,H,dh), bm/cm (B,S,N) or (B,S,G,N), dt (B,S,H), a (H,) ->
+    y (B,S,H,dh) f32."""
     b, s, h, dh = xs.shape
     n = bm.shape[-1]
+    g = bm.shape[2] if bm.ndim == 4 else 1
+    if h % g:
+        raise ValueError(f"{h} heads do not divide into {g} groups")
+    hpg = h // g  # heads per group
     q = min(chunk, s)
     if s % q:
         raise ValueError(f"seq {s} must divide chunk {q}")
+
+    def groups_first(t):  # (B, S, G, N) -> (B*G, S, N); one group: (B, S, N)
+        return jnp.moveaxis(t, 2, 1).reshape(b * g, s, n) if t.ndim == 4 else t
+
+    bm, cm = groups_first(bm), groups_first(cm)
     nc = s // q
     cum = jnp.cumsum((dt * a).reshape(b, nc, q, h), axis=2).reshape(b, s, h)
 
@@ -91,7 +103,7 @@ def ssd_chunked_tpu(xs, bm, cm, dt, a, *, chunk: int = 128, interpret: bool = Fa
 
     row = pl.BlockSpec((1, 1, q), lambda bh, ci: (bh, 0, ci))
     col = pl.BlockSpec((1, q, 1), lambda bh, ci: (bh, ci, 0))
-    proj = pl.BlockSpec((1, q, n), lambda bh, ci: (bh // h, ci, 0))
+    proj = pl.BlockSpec((1, q, n), lambda bh, ci: (bh // hpg, ci, 0))
     y = pl.pallas_call(
         functools.partial(_ssd_kernel, q=q),
         grid=(b * h, nc),

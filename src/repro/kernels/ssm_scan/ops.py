@@ -13,7 +13,8 @@ from repro.kernels.ssm_scan.ref import ssd_ref
 @partial(jax.jit, static_argnames=("chunk", "use_pallas", "interpret"))
 def ssd_chunked(xs, bm, cm, dt, a, *, chunk: int = 128, use_pallas: bool = False,
                 interpret: bool = False):
-    """Chunked selective-state scan.  Returns y (B,S,H,dh) f32."""
+    """Chunked selective-state scan.  ``bm``/``cm`` are (B,S,N) for one B/C
+    group or (B,S,G,N) for G groups.  Returns y (B,S,H,dh) f32."""
     if use_pallas:
         return ssd_chunked_tpu(xs, bm, cm, dt, a, chunk=chunk, interpret=interpret)
     y, _ = ssd_ref(xs, bm, cm, dt, a, chunk=chunk)

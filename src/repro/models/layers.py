@@ -93,6 +93,8 @@ def mlp(cfg, p: dict, x: jax.Array) -> jax.Array:
         h = jax.nn.silu(matmul(x, p["w_gate"])) * matmul(x, p["w_up"])
     elif cfg.mlp_kind == "geglu":
         h = jax.nn.gelu(matmul(x, p["w_gate"]), approximate=True) * matmul(x, p["w_up"])
+    elif cfg.mlp_kind == "relu2":  # squared ReLU, no gate (Nemotron-H)
+        h = jnp.square(jax.nn.relu(matmul(x, p["w_up"]).astype(jnp.float32))).astype(x.dtype)
     else:
         h = jax.nn.gelu(matmul(x, p["w_up"]), approximate=True)
     from repro.models.common import matmul_reduced
@@ -148,6 +150,15 @@ def out_proj(p: dict, o: jax.Array) -> jax.Array:
     return jax.lax.dot_general(
         o, p["wo"], (((o.ndim - 2, o.ndim - 1), (0, 1)), ((), ())),
     ).astype(o.dtype)
+
+
+def self_attention(p: dict, x: jax.Array, attend) -> jax.Array:
+    """Self-attention with no positional encoding (Nemotron-H's attention
+    layers): q/k/v projections -> ``attend(q, k, v)`` -> output projection,
+    under the ``seifer.attn`` name scope."""
+    with jax.named_scope("seifer.attn"):
+        q, k, v = qkv_proj(None, p, x)
+        return out_proj(p, attend(q, k, v))
 
 
 def _softcap(logits: jax.Array, cap: float) -> jax.Array:

@@ -1,4 +1,19 @@
-"""Mixture-of-Experts FFN: top-k routing, capacity-based GShard dispatch.
+"""Mixture-of-Experts FFN layers.
+
+Two layers live here:
+
+* ``moe_mlp``: softmax top-k routing with capacity-based GShard dispatch,
+  used by the LM side stack (``models/lm.py``).
+* ``held_expert_moe``: the layer of one chip under expert parallelism.  It
+  is told which experts it holds, routes every token over all of the
+  router's experts (sigmoid scores, a selection bias, top-k, renormalised
+  and scaled weights, as DeepSeek-V3 and Nemotron-H route), and computes,
+  dropping no token, its held experts' part of the result through the
+  grouped matmul of ``kernels/moe_gmm``, plus the shared expert.  What the
+  experts held elsewhere add is left out: on one chip the layer runs without
+  its exchange.
+
+The rest of this docstring is ``moe_mlp``'s.
 
 The dispatch/combine einsum formulation lowers cleanly under GSPMD: expert
 weights are sharded over the "model" axis (expert parallelism), tokens over
@@ -13,10 +28,17 @@ the auxiliary load-balancing loss keeps drop rates low in training.
 
 from __future__ import annotations
 
+import math
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.moe_gmm import gmm
 from repro.models.common import dense_init
+from repro.models.layers import mlp
+
+RELU2 = SimpleNamespace(mlp_kind="relu2")
 
 DEFAULT_GROUP = 512
 
@@ -91,3 +113,81 @@ def moe_mlp(
     frac_probs = probs.mean(axis=1)  # (G,E)
     aux = e * jnp.mean(jnp.sum(frac_tokens * frac_probs, axis=-1))
     return y.reshape(b, s, d).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# The held experts' share, dropless (expert parallelism)
+# ---------------------------------------------------------------------------
+
+def sigmoid_route(x: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int,
+                  scaling: float) -> tuple[jax.Array, jax.Array]:
+    """Routing over all of the router's experts.  x (T, d).
+
+    Logits in f32 at HIGHEST precision, sigmoid scores; the top ``top_k``
+    of scores + ``bias`` are chosen (``jax.lax.top_k``: on a tie the lower
+    index first); their unbiased scores, renormalised to sum to one and
+    times ``scaling``, weight them.  Returns (experts (T, k) int32,
+    weights (T, k) f32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    return experts, w / (w.sum(-1, keepdims=True) + 1e-20) * scaling
+
+
+def sort_by_expert(picks: jax.Array, n_experts: int):
+    """A stable counting sort of ``picks`` (m,) int32 by expert.
+
+    Returns ``order`` (m,): the picks' indices sorted by expert, ties in
+    index order; ``dest`` (m,): each pick's row in that order (the inverse
+    of ``order``); and ``sizes`` (n_experts,) int32.  A pick's row is its
+    expert's first row plus the picks of that expert before it, counted in
+    blocks of 128 by a strictly lower-triangular matmul of the one-hot picks
+    (0/1 in bf16, sums in f32: exact), so no sort runs."""
+    m = picks.shape[0]
+    blk = math.gcd(m, 128)
+    onehot = jax.nn.one_hot(picks, n_experts, dtype=jnp.bfloat16).reshape(m // blk, blk, n_experts)
+    earlier = jnp.tril(jnp.ones((blk, blk), jnp.bfloat16), -1)
+    within = jnp.einsum("ij,bjn->bin", earlier, onehot, preferred_element_type=jnp.float32)
+    per_block = onehot.sum(axis=1, dtype=jnp.float32)
+    before = jnp.cumsum(per_block, axis=0) - per_block  # in earlier blocks
+    sizes = per_block.sum(axis=0)
+    first = jnp.cumsum(sizes) - sizes
+    rank = jnp.sum((within + before[:, None]) * onehot, axis=-1).reshape(m)
+    dest = (first[picks] + rank).astype(jnp.int32)
+    order = jnp.zeros((m,), jnp.int32).at[dest].set(
+        jnp.arange(m, dtype=jnp.int32), unique_indices=True)
+    return order, dest, sizes.astype(jnp.int32)
+
+
+def held_expert_moe(p: dict, x: jax.Array, *, top_k: int, scaling: float,
+                    first_expert: int = 0, use_pallas: bool = False,
+                    interpret: bool = False) -> jax.Array:
+    """The part of a routed-expert layer that experts ``first_expert ..
+    first_expert + held - 1`` give, plus the shared expert.  x (..., d).
+
+    ``p``: ``router`` (d, E) and ``bias`` (E,) over all E experts, ``w_up``
+    (held, d, f) and ``w_down`` (held, f, d) of the held experts (relu^2,
+    no gate), and ``shared``, the shared expert's ``mlp`` (kind relu2).  Every
+    (token, pick) pair is sorted by expert (``sort_by_expert``), the held
+    experts' rows go through ``kernels.moe_gmm.gmm``, relu^2 and the down
+    projection, each row is un-sorted back to its pick and weighted, and a
+    token's picks are summed.  Nothing is dropped: the buffers hold every
+    pick, so even all picks on one held expert are computed."""
+    with jax.named_scope("seifer.moe"):
+        shape = x.shape
+        xt = x.reshape(-1, shape[-1])
+        t = xt.shape[0]
+        experts, w = sigmoid_route(xt, p["router"], p["bias"], top_k=top_k, scaling=scaling)
+        order, dest, sizes = sort_by_expert(experts.reshape(-1), p["router"].shape[1])
+        knob = dict(group_offset=first_expert, out_dtype=x.dtype,
+                    use_pallas=use_pallas, interpret=interpret)
+        h = gmm(xt[order // top_k], p["w_up"], sizes, **knob)
+        h = jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(x.dtype)
+        y = gmm(h, p["w_down"], sizes, **knob)
+        # pick i of token i // top_k is row dest[i] of y
+        y = y[dest].astype(jnp.float32) * w.reshape(-1, 1)
+        routed = y.reshape(t, top_k, -1).sum(axis=1)
+        shared = mlp(RELU2, p["shared"], xt).astype(jnp.float32)
+        return (routed + shared).astype(x.dtype).reshape(shape)

@@ -282,21 +282,66 @@ def test_quantize_scale_equivariance():
 # ssm_scan (chunked SSD)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("dims", [(2, 256, 4, 64, 32, 64), (1, 512, 8, 64, 64, 128)])
+@pytest.mark.parametrize("dims", [(2, 256, 4, 64, 32, 64), (1, 512, 8, 64, 64, 128),
+                                  (2, 256, 4, 64, 32, 64, 2), (1, 256, 8, 64, 32, 128, 8)])
 def test_ssd_pallas_matches_ref(dims):
+    """The kernel against the reference; a seventh entry gives B/C groups."""
     from repro.kernels.ssm_scan.ops import ssd_chunked
     from repro.kernels.ssm_scan.ref import ssd_ref
 
-    b, s, h, hd, n, q = dims
+    b, s, h, hd, n, q = dims[:6]
+    bc = (b, s, n) if len(dims) == 6 else (b, s, dims[6], n)
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     xs = jax.random.normal(ks[0], (b, s, h, hd), jnp.float32) * 0.5
-    bm = jax.random.normal(ks[1], (b, s, n)) * 0.5
-    cm = jax.random.normal(ks[2], (b, s, n)) * 0.5
+    bm = jax.random.normal(ks[1], bc) * 0.5
+    cm = jax.random.normal(ks[2], bc) * 0.5
     dt = jax.nn.softplus(jax.random.normal(ks[3], (b, s, h)))
     a = -jnp.exp(jax.random.normal(ks[4], (h,)) * 0.3)
     y_ref, _ = ssd_ref(xs, bm, cm, dt, a, chunk=q)
     y_pal = ssd_chunked(xs, bm, cm, dt, a, chunk=q, use_pallas=True, interpret=True)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_pal), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("groups", [1, 2, 8])
+def test_grouped_ssd_is_each_head_on_its_group(groups):
+    """Grouped SSD, kernel and reference, equals each head scanned alone
+    with its group's B and C (head h reads group h // (H/G))."""
+    from repro.kernels.ssm_scan.ops import ssd_chunked
+    from repro.kernels.ssm_scan.ref import ssd_ref
+
+    b, s, h, hd, n, q = 2, 64, 8, 64, 16, 32
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    xs = jax.random.normal(ks[0], (b, s, h, hd)) * 0.5
+    bm = jax.random.normal(ks[1], (b, s, groups, n)) * 0.5
+    cm = jax.random.normal(ks[2], (b, s, groups, n)) * 0.5
+    dt = jax.nn.softplus(jax.random.normal(ks[3], (b, s, h)))
+    a = -jnp.exp(jax.random.normal(ks[4], (h,)) * 0.3)
+    g = [i // (h // groups) for i in range(h)]
+    want = jnp.concatenate([
+        ssd_ref(xs[:, :, i:i + 1], bm[:, :, g[i]], cm[:, :, g[i]], dt[:, :, i:i + 1],
+                a[i:i + 1], chunk=q)[0] for i in range(h)], axis=2)
+    got_ref = ssd_ref(xs, bm, cm, dt, a, chunk=q)[0]
+    got_pal = ssd_chunked(xs, bm, cm, dt, a, chunk=q, use_pallas=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want), atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_pal), np.asarray(want), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("groups", [1, 8])
+def test_ssd_kernel_operands_fold_groups(groups):
+    """The kernel's pallas_call takes 7 operands, B and C as (B*G, S, N):
+    for one group exactly (B, S, N), as before groups."""
+    from repro.kernels.ssm_scan.kernel import ssd_chunked_tpu
+
+    b, s, h, hd, n = 2, 256, 64, 64, 128
+    bc = (b, s, n) if groups == 1 else (b, s, groups, n)
+    specs = [jax.ShapeDtypeStruct(x, jnp.float32)
+             for x in ((b, s, h, hd), bc, bc, (b, s, h), (h,))]
+    jaxpr = jax.make_jaxpr(lambda *a: ssd_chunked_tpu(*a, chunk=128, interpret=True))(*specs)
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    shapes = [v.aval.shape for v in calls[0].invars]
+    assert shapes == [(b * h, s, hd), (b * groups, s, n), (b * groups, s, n),
+                      (b * h, 1, s), (b * h, 1, s), (b * h, s, 1), (b * h, s, 1)]
 
 
 def test_ssd_chunk_invariance():

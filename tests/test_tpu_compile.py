@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention.kernel import flash_attention_tpu
+from repro.kernels.moe_gmm.kernel import gmm_tpu
 from repro.kernels.quantize.kernel import (
     dequant_matmul_tpu,
     dequantize_int8_tpu,
@@ -46,7 +47,7 @@ def one_chip():
             compilation_cache.reset_cache()
 
 
-F32, I8 = jnp.float32, jnp.int8
+F32, I8, BF16, I32 = jnp.float32, jnp.int8, jnp.bfloat16, jnp.int32
 # demo_transformer: (batch, seq 256, d 32), 4 query / 2 kv heads of dim 8;
 # demo_ssm: (batch, seq 8, d 24), 2 heads of dim 12, state 4
 CASES = {
@@ -84,6 +85,20 @@ CASES = {
         lambda xs, bm, cm, dt, a: ssd_chunked_tpu(xs, bm, cm, dt, a, chunk=128),
         [((1, 512, 8, 64), F32), ((1, 512, 64), F32), ((1, 512, 64), F32),
          ((1, 512, 8), F32), ((8,), F32)]),
+    # nemotron-3-nano-30b-a3b.ep8 at its cell's shapes: a batch of 8 x 2048
+    # tokens, 64 heads of 64 in 8 B/C groups of state 128; 16 of 128
+    # experts held, 8 x 2048 x 6 sorted picks through the up and down
+    # projections
+    "ssd_nemotron_groups8": (
+        lambda xs, bm, cm, dt, a: ssd_chunked_tpu(xs, bm, cm, dt, a, chunk=128),
+        [((8, 2048, 64, 64), BF16), ((8, 2048, 8, 128), F32), ((8, 2048, 8, 128), F32),
+         ((8, 2048, 64), F32), ((64,), F32)]),
+    "gmm_nemotron_up": (
+        lambda x, w, sizes: gmm_tpu(x, w, sizes, out_dtype=BF16),
+        [((98304, 2688), BF16), ((16, 2688, 1856), BF16), ((128,), I32)]),
+    "gmm_nemotron_down": (
+        lambda x, w, sizes: gmm_tpu(x, w, sizes, out_dtype=BF16),
+        [((98304, 1856), BF16), ((16, 1856, 2688), BF16), ((128,), I32)]),
 }
 
 
@@ -150,3 +165,33 @@ def test_compiled_stage_keeps_its_kernels_for_v5e(model, one_chip):
     else:
         assert all(len(ins) == 3 and all(len(s) == 3 for _, s in ins)
                    for _, _, ins in calls)
+
+
+@pytest.mark.parametrize("name,reader", [
+    ("ssd_nemotron_groups8", "bench.metrics.ssd_scan_roofline"),
+    ("gmm_nemotron_up", "bench.metrics.moe_gmm_roofline"),
+])
+def test_nemotron_kernels_are_what_their_readers_match_for_v5e(name, reader, one_chip):
+    """The grouped SSD and the grouped matmul compiled at the cell's shapes,
+    through the entry points the blocks call, are the custom calls their
+    roofline readers pick: the SSD's B and C as (B*G, S, N) among 7
+    operands, the gmm's rows and held weights last."""
+    import importlib
+    from functools import partial
+
+    from repro.kernels.moe_gmm import gmm
+    from repro.kernels.ssm_scan import ssd_chunked
+
+    entry = {"ssd_nemotron_groups8": partial(ssd_chunked, chunk=128, use_pallas=True),
+             "gmm_nemotron_up": partial(gmm, out_dtype=BF16, use_pallas=True)}
+    fn, shapes = entry[name], CASES[name][1]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    calls = kernel_calls(jax.jit(fn).lower(*args).compile().as_text())
+    match = importlib.import_module(reader)._match
+    found = [(n, outs, ins) for n, outs, ins in calls if match(n, outs, ins)]
+    assert len(found) == 1, calls
+    _, _, ins = found[0]
+    if name.startswith("ssd"):
+        assert len(ins) == 7 and ins[1][1] == (8 * 8, 2048, 128)
+    else:
+        assert ins[-2][1] == (98304, 2688) and ins[-1][1] == (16, 2688, 1856)
