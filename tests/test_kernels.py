@@ -283,9 +283,13 @@ def test_quantize_scale_equivariance():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dims", [(2, 256, 4, 64, 32, 64), (1, 512, 8, 64, 64, 128),
-                                  (2, 256, 4, 64, 32, 64, 2), (1, 256, 8, 64, 32, 128, 8)])
+                                  (2, 256, 4, 64, 32, 64, 2), (1, 256, 8, 64, 32, 128, 8),
+                                  (1, 256, 80, 64, 128, 128), (1, 256, 64, 64, 128, 128, 8),
+                                  (2, 256, 6, 64, 32, 128, 2)])
 def test_ssd_pallas_matches_ref(dims):
-    """The kernel against the reference; a seventh entry gives B/C groups."""
+    """The kernel against the reference; a seventh entry gives B/C groups.
+    The last three are mamba2-2.7b's heads (80 in one group), Nemotron's (64
+    in 8 groups) and 3 heads a group, which blocks of 8 heads cannot split."""
     from repro.kernels.ssm_scan.ops import ssd_chunked
     from repro.kernels.ssm_scan.ref import ssd_ref
 
@@ -328,9 +332,12 @@ def test_grouped_ssd_is_each_head_on_its_group(groups):
 
 @pytest.mark.parametrize("groups", [1, 8])
 def test_ssd_kernel_operands_fold_groups(groups):
-    """The kernel's pallas_call takes 7 operands, B and C as (B*G, S, N):
-    for one group exactly (B, S, N), as before groups."""
-    from repro.kernels.ssm_scan.kernel import ssd_chunked_tpu
+    """The kernel's pallas_call takes 7 operands: x as (B*H, S, dh), B and C
+    as (B*G, S, N) -- for one group exactly (B, S, N), as before groups --
+    then four per-position vectors as (B*H/hb, hb, S) rows; no operand ends
+    in a dimension of 1.  y comes out (B*H/hb, hb*dh, S), positions on the
+    lanes."""
+    from repro.kernels.ssm_scan.kernel import ssd_blocking, ssd_chunked_tpu
 
     b, s, h, hd, n = 2, 256, 64, 64, 128
     bc = (b, s, n) if groups == 1 else (b, s, groups, n)
@@ -340,8 +347,31 @@ def test_ssd_kernel_operands_fold_groups(groups):
     calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
     shapes = [v.aval.shape for v in calls[0].invars]
-    assert shapes == [(b * h, s, hd), (b * groups, s, n), (b * groups, s, n),
-                      (b * h, 1, s), (b * h, 1, s), (b * h, s, 1), (b * h, s, 1)]
+    hb = ssd_blocking(b, s, h, groups, hd, n, 128)[0]
+    assert hb == (32 if groups == 1 else 8)
+    assert shapes == [(b * h, s, hd), (b * groups, s, n), (b * groups, s, n)] + [
+        (b * h // hb, hb, s)] * 4
+    assert all(shape[-1] != 1 for shape in shapes)
+    assert [v.aval.shape for v in calls[0].outvars] == [(b * h // hb, hb * hd, s)]
+
+
+@pytest.mark.parametrize("shape,hb", [
+    ((8, 2048, 80, 1, 64, 128, 128), 20),  # mamba2-2.7b: 80 heads, one group
+    ((8, 2048, 64, 8, 64, 128, 128), 8),  # nemotron: 8 heads a group
+    ((2, 256, 6, 2, 64, 32, 128), 3),  # 3 heads a group
+    ((4, 8, 2, 1, 12, 4, 8), 2),  # demo_ssm: both heads
+    ((1, 256, 4, 4, 64, 32, 128), 1),  # one head a group
+])
+def test_ssd_blocking_follows_the_shapes(shape, hb):
+    """Heads per step: the largest divisor of the heads per group that fits
+    the VMEM budget; the grid covers every head block and chunk once."""
+    from repro.kernels.ssm_scan.kernel import VMEM_BUDGET, _step_vmem_bytes, ssd_blocking
+
+    b, s, h, g, dh, n, q = shape
+    got, grid = ssd_blocking(*shape)
+    assert (got, grid) == (hb, (b * h // hb, s // q))
+    assert (h // g) % got == 0
+    assert got == 1 or _step_vmem_bytes(got, q, dh, n) <= VMEM_BUDGET
 
 
 def test_ssd_chunk_invariance():

@@ -93,6 +93,12 @@ CASES = {
         lambda xs, bm, cm, dt, a: ssd_chunked_tpu(xs, bm, cm, dt, a, chunk=128),
         [((8, 2048, 64, 64), BF16), ((8, 2048, 8, 128), F32), ((8, 2048, 8, 128), F32),
          ((8, 2048, 64), F32), ((64,), F32)]),
+    # mamba2-2.7b.sat-int8 at its cell's shapes: 80 heads of 64 in one B/C
+    # group of state 128
+    "ssd_mamba_cell": (
+        lambda xs, bm, cm, dt, a: ssd_chunked_tpu(xs, bm, cm, dt, a, chunk=128),
+        [((8, 2048, 80, 64), BF16), ((8, 2048, 128), F32), ((8, 2048, 128), F32),
+         ((8, 2048, 80), F32), ((80,), F32)]),
     "gmm_nemotron_up": (
         lambda x, w, sizes: gmm_tpu(x, w, sizes, out_dtype=BF16),
         [((98304, 2688), BF16), ((16, 2688, 1856), BF16), ((128,), I32)]),
@@ -195,3 +201,76 @@ def test_nemotron_kernels_are_what_their_readers_match_for_v5e(name, reader, one
         assert len(ins) == 7 and ins[1][1] == (8 * 8, 2048, 128)
     else:
         assert ins[-2][1] == (98304, 2688) and ins[-1][1] == (16, 2688, 1856)
+
+
+# what the SSD's roofline reader priced at the parent commit, and the
+# temporaries the parent's compiled SSD held (v5e, no chip): (ins[0], ins[1],
+# ssd_ops_bytes, temp bytes)
+SSD_CELLS = {
+    "ssd_mamba_cell": (("bf16", (640, 2048, 64)), ("f32", (8, 2048, 128)),
+                       (64961380352.0, 530579456.0), 2013685248),
+    "ssd_nemotron_groups8": (("bf16", (512, 2048, 64)), ("f32", (64, 2048, 128)),
+                             (55834574848.0, 545259520.0), 1677915136),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SSD_CELLS))
+def test_ssd_at_cell_shapes_is_priced_as_before_for_v5e(name, one_chip):
+    """The SSD entry compiled at each SSM cell's shapes holds one custom call
+    that ``ssd_scan_roofline`` matches, priced from the same operand shapes
+    (so the same operations and bytes) as at the parent, and under half the
+    parent's temporaries (no padded (., S, 1) columns, y in its own layout)."""
+    from functools import partial
+    from types import SimpleNamespace
+
+    from bench.metrics import ssd_scan_roofline as reader
+    from repro.kernels.ssm_scan import ssd_chunked
+
+    x0, x1, cost, parent_temp = SSD_CELLS[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in CASES[name][1]]
+    compiled = jax.jit(partial(ssd_chunked, chunk=128, use_pallas=True)).lower(*args).compile()
+    found = [(n, outs, ins) for n, outs, ins in kernel_calls(compiled.as_text())
+             if reader._match(n, outs, ins)]
+    assert len(found) == 1, found
+    _, outs, ins = found[0]
+    assert len(ins) == 7 and ins[0] == x0 and ins[1] == ins[2] == x1
+    run = SimpleNamespace(cfg={"assumed": {"kernel_chunk": 128}})
+    assert reader._cost(outs, ins, run) == cost
+    assert compiled.memory_analysis().temp_size_in_bytes < parent_temp / 2
+
+
+@pytest.mark.parametrize("name,groups,d", [("ssd_mamba_cell", 1, 2560),
+                                           ("ssd_nemotron_groups8", 8, 2688)])
+def test_mixer_reads_the_ssd_output_in_place_for_v5e(name, groups, d, one_chip):
+    """The mixer's tail after the SSD (+ D x, grouped gated norm,
+    out-projection) compiled at each SSM cell's shapes reads the kernel's
+    output as it lies: no copy takes the ``ssd_chunked`` call's result."""
+    import re
+    from types import SimpleNamespace
+
+    from repro.kernels.ssm_scan import ssd_chunked
+    from repro.models import ssm
+
+    shapes = CASES[name][1]
+    b, s, h, dh = shapes[0][0]
+    cfg = SimpleNamespace(d_model=d, ssm_expand=2, ssm_state=128, ssm_conv_width=4,
+                          ssm_groups=groups, ssm_heads=h)
+
+    def tail(xs, bm, cm, dt, a, dskip, z, norm_w, out_proj):
+        y = ssd_chunked(xs, bm, cm, dt, a, chunk=128, use_pallas=True)
+        y = y + xs.astype(F32) * dskip[None, None, :, None]
+        p = {"norm_w": norm_w, "out_proj": out_proj}
+        return ssm._gate_out(cfg, p, y.reshape(b, s, h * dh), z)
+
+    shapes = shapes + [((h,), F32), ((b, s, h * dh), BF16), ((h * dh,), F32),
+                       ((h * dh, d), BF16)]
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip) for sh, dt in shapes]
+    text = jax.jit(tail).lower(*args).compile().as_text()
+    kernel = re.findall(r"%(ssd_chunked[\w.]*) = ", text)
+    assert len(kernel) == 1, kernel
+    views = set(kernel)  # the kernel's result and its bitcasts
+    for dst, src in re.findall(r"%([\w.\-]+) = \S+ bitcast\(%([\w.\-]+)\)", text):
+        if src in views:
+            views.add(dst)
+    copied = re.findall(r"= \S+ copy\(%([\w.\-]+)\)", text)
+    assert not views & set(copied), sorted(views & set(copied))
