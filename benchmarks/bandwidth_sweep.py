@@ -64,7 +64,6 @@ def _measure(codec: str, mesh_bw: float, requests: int, seed: int) -> dict:
         codec=codec,
         seed=seed,
         microbatch=1,  # measured requests/s == predicted microbatch rate
-        serving="pipelined",
     ))
     for _ in range(requests):
         dep.submit(jnp.ones((WIDTH,)) * 0.1)

@@ -16,13 +16,13 @@ throughput (req/s).  Recovery is demonstrated by phase-3 and phase-5
 throughput returning to within a small factor of phase 1.  All convergence
 goes through ``Deployment.inject`` + the serving loop's reconcile -- no
 manual ``Dispatcher.recover()``-style calls.  Serving runs through the
-pipelined discrete-event engine by default (``--serving sync`` falls back
-to the synchronous baseline), so recovery cost includes requeueing exactly
-the microbatches resident on the affected stages.  The partition/placement
-strategies are registry names, so the same scenario measures any pair:
+pipelined discrete-event engine, so recovery cost includes requeueing
+exactly the microbatches resident on the affected stages.  The
+partition/placement strategies are registry names, so the same scenario
+measures any pair:
 
   PYTHONPATH=src python -m benchmarks.churn_throughput [--smoke]
-      [--partitioner NAME] [--placer NAME] [--serving pipelined|sync]
+      [--partitioner NAME] [--placer NAME]
 """
 
 from __future__ import annotations
@@ -71,7 +71,6 @@ def run(
     seed: int = 0,
     partitioner: str | None = None,
     placer: str | None = None,
-    serving: str = "pipelined",
 ) -> dict:
     graph, executor_for_version = demo_mlp(d=D)
     spec = DeploymentSpec(
@@ -85,7 +84,6 @@ def run(
         placer=placer,
         seed=seed,
         microbatch=microbatch,
-        serving=serving,
     )
     dep = deploy(spec)
     strategies = dict(dep.plan.strategies)
@@ -117,7 +115,7 @@ def run(
     payload = {
         "rows": rows,
         "strategies": strategies,
-        "serving_mode": m["serving"].get("mode", "sync"),
+        "serving_mode": m["serving"]["mode"],
         "stages": m["serving"].get("stages", []),
         "requeued_microbatches": m["serving"].get("requeued_microbatches", 0),
         "actions": actions,
@@ -154,12 +152,11 @@ def main() -> int:
     ap.add_argument("--microbatch", type=int, default=4)
     ap.add_argument("--partitioner", default=None)
     ap.add_argument("--placer", default=None)
-    ap.add_argument("--serving", default="pipelined", choices=("pipelined", "sync"))
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     per_phase = args.per_phase if args.per_phase is not None else (8 if args.smoke else 40)
     run(per_phase=per_phase, microbatch=args.microbatch, seed=args.seed,
-        partitioner=args.partitioner, placer=args.placer, serving=args.serving)
+        partitioner=args.partitioner, placer=args.placer)
     return 0
 
 

@@ -12,9 +12,9 @@ every convergence step driven by typed events.
 Expected output (paths/latencies vary slightly with placement seeds):
 
     bootstrap: 4 partitions on nodes [4, 3, 5, 2], bottleneck 0.159 ms
-    served 8 requests, clock 0.107 ms
-    NodeFailed(3) -> [('replace', 're-placed 1 pod(s) off node 3')]
-    recovered: path [2, 5, 6, 1], outputs identical: True
+    served 8 requests, clock 0.486 ms
+    NodeFailed(3) -> [('replace', 're-placed 1 pod(s) off node 3; ...')]
+    recovered: path [2, 8, 6, 1], outputs identical: True
     VersionBumped(1) -> [('redeploy', 'in-place redeploy at v1')]
     generation still 0 (no cluster restart on a version bump)
     replan(placer='greedy') -> path [...], still v1, generation 0
@@ -30,7 +30,7 @@ from repro.core.model_zoo import demo_mlp
 
 # --- the spec: an executable 8-layer tanh-MLP (weights keyed by model
 # version, so a VersionBumped redeploy visibly changes the function) on a
-# seeded random wireless cluster, int8 boundary compression ------------------
+# seeded random wireless cluster, int8 codec on every inter-stage link -------
 D = 32
 graph, executor_for_version = demo_mlp(d=D)
 spec = DeploymentSpec(
@@ -39,7 +39,7 @@ spec = DeploymentSpec(
     cluster=ClusterSpec(
         n_nodes=8, capacity_bytes=graph.total_param_bytes / 3, seed=3,
     ),
-    compression_ratio=2.0,  # int8 boundaries
+    codec="int8",  # blockwise int8 on the inter-stage links
     seed=0,
     microbatch=4,
 )

@@ -18,12 +18,12 @@ Expected output (exact numbers vary with the cluster seed):
     model: resnet50, 18 layers, 25.5 MB int8 weights
     partitions: 4, cuts at (12, 14, 15), max boundary 0.80 MB
     placement: nodes (2, 3, 5, 1), bottleneck 47.05 ms, throughput 21.3 inf/s
-    compression 1x: period 1059.40 ms, effective throughput 0.9 inf/s
-    compression 2x: period 1059.40 ms, effective throughput 0.9 inf/s
+    codec identity: period 1059.40 ms, effective throughput 0.9 inf/s
+    codec int8: period 1059.40 ms, effective throughput 0.9 inf/s
 
-(The 2x row matches 1x here because this cluster's period is compute-bound;
-on a bandwidth-bound cluster, compression halves the period -- see
-``benchmarks/fig3_bottleneck.py``.)
+(The int8 row matches identity here because this cluster's period is
+compute-bound; on a bandwidth-bound cluster, the int8 link codec cuts the
+wire time about 4x -- see ``benchmarks/bandwidth_sweep.py``.)
 """
 
 from repro.api import (
@@ -35,6 +35,7 @@ from repro.api import (
 )
 from repro.core import evaluate_pipeline
 from repro.core.model_zoo import resnet50
+from repro.dataplane import assign_link_codecs, hop_bytes
 
 # 0. every algorithm is a named, registered strategy (default marked *)
 print("registered strategies:")
@@ -64,11 +65,13 @@ print(f"placement: nodes {place.path}, "
       f"bottleneck {place.bottleneck_latency*1e3:.2f} ms, "
       f"throughput {place.throughput:.1f} inf/s")
 
-# 3. end-to-end metrics, with and without boundary compression (ZFP/LZ4
-#    on the edge; blockwise int8 on TPU -- see kernels/quantize)
+# 3. end-to-end metrics, raw links vs the int8 link codec (ZFP/LZ4 on the
+#    edge; blockwise int8 here -- see repro.dataplane and kernels/quantize)
 comm, _ = spec.cluster.build()
-for ratio in (1.0, 2.0):
+for codec in ("identity", "int8"):
+    codecs = assign_link_codecs(hop_bytes(part.boundaries), place.path,
+                                comm.bw, codec=codec)
     m = evaluate_pipeline(part.partitions, place.path, comm,
-                          device_flops=5e9, compression_ratio=ratio)
-    print(f"compression {ratio:.0f}x: period {m.pipeline_period*1e3:.2f} ms, "
+                          device_flops=5e9, codecs=codecs)
+    print(f"codec {codec}: period {m.pipeline_period*1e3:.2f} ms, "
           f"effective throughput {m.effective_throughput:.1f} inf/s")

@@ -2,7 +2,7 @@
 
 Before the declarative API, standing up a SEIFER deployment meant hand-wiring
 six objects (``LayerGraph`` -> ``EdgeCluster`` -> ``ArtifactStore`` ->
-``ControlPlane`` -> bootstrap -> ``ServingLoop``), repeated in every example
+``ControlPlane`` -> bootstrap -> serving engine), repeated in every example
 and benchmark.  ``deploy()`` collapses that to one call: it validates the
 spec, materializes the cluster, bootstraps the control plane through the
 spec's strategies (Sec. 2.1-2.2: elect -> probe -> partition -> place ->
@@ -35,7 +35,7 @@ from repro.cluster.controlplane import (
 from repro.cluster.engine import PipelinedServingLoop, ReplicatedServingLoop
 from repro.cluster.events import ClusterEvent, NodeJoined, VersionBumped
 from repro.cluster.lifecycle import EdgeCluster
-from repro.cluster.serving import Request, ServingLoop
+from repro.cluster.serving import Request
 from repro.cluster.store import ArtifactStore
 from repro.cluster.watch import ModelWatcher
 from repro.obs import (
@@ -144,7 +144,6 @@ def _build_deployment(
             graph, view,
             replicas=spec.replicas, capacity=spec.capacity, version=version,
             dispatcher=0, device_flops=flops_per_s,
-            compression_ratio=spec.compression_ratio,
         )
         if not rplan.feasible:
             raise InfeasibleSpecError((SpecIssue(
@@ -160,7 +159,7 @@ def _build_deployment(
             cluster, store,
             lambda v: graph, executor_for_version,
             planner=Planner.from_spec(spec),
-            capacity=spec.capacity, compression_ratio=spec.compression_ratio,
+            capacity=spec.capacity,
             seed=spec.seed + seed_offset,
             allowed_nodes=None if nodes is None else set(nodes) | {0},
             hosting_nodes=None if nodes is None else set(nodes),
@@ -177,7 +176,6 @@ def _build_deployment(
                 lambda v: graph, executor_for_version,
                 planner=Planner.from_spec(spec),
                 capacity=spec.capacity,
-                compression_ratio=spec.compression_ratio,
                 # distinct probe-noise streams per replica (and per tenant)
                 seed=spec.seed + seed_offset + 7919 * r,
                 allowed_nodes=set(group) | {0},
@@ -225,7 +223,6 @@ def _deploy_autoscaled(
         graph, view,
         replicas=plan_width, capacity=spec.capacity, version=version,
         dispatcher=0, device_flops=flops_per_s,
-        compression_ratio=spec.compression_ratio,
     )
     if not rplan.feasible or rplan.n_replicas < auto.min_replicas:
         raise InfeasibleSpecError((SpecIssue(
@@ -243,7 +240,6 @@ def _deploy_autoscaled(
             lambda v: graph, executor_for_version,
             planner=Planner.from_spec(spec),
             capacity=spec.capacity,
-            compression_ratio=spec.compression_ratio,
             seed=spec.seed + seed_offset + 7919 * r,
             allowed_nodes=set(group) | {0},
             hosting_nodes=set(group),
@@ -319,21 +315,15 @@ class Deployment:
             )
         else:
             self.control = control
-            if spec.serving == "sync":
-                self.loop = ServingLoop(
-                    control, microbatch=spec.microbatch,
-                    tracer=self.tracer, registry=self.registry,
-                )
-            else:
-                self.loop = PipelinedServingLoop(
-                    control, microbatch=spec.microbatch,
-                    queue_depth=spec.queue_depth,
-                    max_batch=spec.max_batch,
-                    admission_depth=spec.admission_depth,
-                    class_priority=spec.class_priority(),
-                    class_targets=spec.class_targets(),
-                    tracer=self.tracer, registry=self.registry,
-                )
+            self.loop = PipelinedServingLoop(
+                control, microbatch=spec.microbatch,
+                queue_depth=spec.queue_depth,
+                max_batch=spec.max_batch,
+                admission_depth=spec.admission_depth,
+                class_priority=spec.class_priority(),
+                class_targets=spec.class_targets(),
+                tracer=self.tracer, registry=self.registry,
+            )
         # journal records are stamped off the serving clock from here on
         self.journal.bind_clock(lambda: self.loop.clock_s)
         self.watcher = ModelWatcher(self.control.store)
@@ -380,16 +370,12 @@ class Deployment:
     # -- serving -------------------------------------------------------------
     def submit(self, x: Any, *, slo_class: str | None = None) -> Request:
         """Admit one inference request."""
-        if self.spec.serving == "sync":
-            return self.loop.submit(x)
         return self.loop.submit(x, slo_class=slo_class)
 
     def schedule(
         self, x: Any, at_s: float, *, slo_class: str | None = None,
     ) -> Request:
         """Register one open-loop arrival at virtual time ``at_s``."""
-        if self.spec.serving == "sync":
-            raise RuntimeError("open-loop arrivals need pipelined serving")
         return self.loop.schedule(x, at_s, slo_class=slo_class)
 
     def submit_trace(self, trace=None, make_input=None) -> int:

@@ -3,10 +3,9 @@
 The planner is the *policy-free* middle of the API: it resolves strategy
 names through the registry, runs partition -> placement (or a joint
 optimizer), and scores the result with the simulator's pipeline metrics --
-no cluster machinery, no pods.  ``Plan`` subsumes the old
-``dispatcher.DeploymentPlan`` (same ``version``/``partition``/``placement``
-fields, so ``Dispatcher.deploy`` consumes it unchanged) and adds the
-predicted bottleneck/throughput plus the strategy names that produced it.
+no cluster machinery, no pods.  A ``Plan`` holds the partition and
+placement ``Dispatcher.deploy`` consumes, the predicted bottleneck and
+throughput, the codec per hop, and the strategy names that produced it.
 
 Strategy functions keep their natural signatures; the planner passes each
 one only the keyword arguments it accepts (``inspect.signature``-filtered),
@@ -27,6 +26,7 @@ from repro.core.bottleneck import evaluate_pipeline
 from repro.core.graph import LayerGraph
 from repro.core.partitioner import PartitionResult
 from repro.core.placement import CommGraph, PlacementResult
+from repro.dataplane import assign_link_codecs, hop_bytes
 
 if TYPE_CHECKING:
     from repro.api.spec import DeploymentSpec, SpecIssue
@@ -36,9 +36,8 @@ if TYPE_CHECKING:
 class Plan:
     """A compiled deployment: partition + placement + predicted metrics.
 
-    Drop-in for the old ``dispatcher.DeploymentPlan`` (which is now an alias
-    of this class): ``Dispatcher.deploy`` reads ``version``, ``partition``,
-    ``placement``, ``feasible``.
+    ``Dispatcher.deploy`` reads ``version``, ``partition``, ``placement``,
+    ``codecs`` and ``feasible``.
     """
 
     version: int
@@ -46,7 +45,7 @@ class Plan:
     placement: PlacementResult
     # the placement objective: max link latency on UNCOMPRESSED boundaries
     predicted_bottleneck_s: float = float("inf")
-    # 1 / pipeline period, codec-, compression- and compute-aware
+    # 1 / pipeline period, codec- and compute-aware
     predicted_throughput: float = 0.0
     strategies: tuple[tuple[str, str], ...] = ()  # (kind, name) pairs
     # transfer codec per hop (len n_parts + 1); () = all-identity legacy plan
@@ -417,7 +416,6 @@ class Planner:
         include_dispatcher: bool = True,
         dispatcher: int | None = None,
         device_flops: float | Sequence[float] | None = None,
-        compression_ratio: float = 1.0,
     ) -> Plan:
         """Partition + place ``graph`` on ``comm``; score the result.
 
@@ -459,16 +457,14 @@ class Planner:
         if not (part.feasible and place.feasible):
             return Plan(version, part, place, strategies=self.strategy_names())
         codecs = self.assign_codecs(
-            [in_bytes, *(p.out_bytes for p in part.partitions[:-1]), out_bytes],
+            hop_bytes(part.boundaries, in_bytes, out_bytes),
             place.path, comm.bw,
             dispatcher=dispatcher, flops_per_node=device_flops,
-            compression_ratio=compression_ratio,
         )
         metrics = evaluate_pipeline(
             part.partitions, place.path, comm,
             device_flops=device_flops, in_bytes=in_bytes, out_bytes=out_bytes,
-            dispatcher=dispatcher, compression_ratio=compression_ratio,
-            codecs=codecs,
+            dispatcher=dispatcher, codecs=codecs,
         )
         return Plan(
             version, part, place,
@@ -486,20 +482,16 @@ class Planner:
         *,
         dispatcher: int | None = None,
         flops_per_node=None,
-        compression_ratio: float = 1.0,
     ) -> tuple[str, ...]:
         """Codec-per-hop for a placed pipeline, under this planner's codec
         config (a fixed name on every inter-stage hop, or the ``"auto"``
         per-link optimum within ``accuracy_tolerance``).  Also the recovery
         path's entry point: a re-placement changes the links, so the
         dispatcher re-runs the assignment for the new path."""
-        from repro.dataplane import assign_link_codecs
-
         return assign_link_codecs(
             hop_bytes, path, bw,
             codec=self.codec, tolerance=self.accuracy_tolerance,
             flops_per_node=flops_per_node, dispatcher=dispatcher,
-            compression_ratio=compression_ratio,
         )
 
     def place(
@@ -548,7 +540,6 @@ class Planner:
         include_dispatcher: bool = True,
         dispatcher: int | None = None,
         device_flops: float | Sequence[float] | None = None,
-        compression_ratio: float = 1.0,
     ) -> ReplicatedPlan:
         """Split the cluster into R disjoint sub-clusters and plan one
         pipeline per sub-cluster with the registered strategies.
@@ -618,7 +609,6 @@ class Planner:
                     capacity=cap, version=version, max_parts=len(g),
                     seed=seed, include_dispatcher=include_dispatcher,
                     dispatcher=dispatcher, device_flops=device_flops,
-                    compression_ratio=compression_ratio,
                 ))
             cand = ReplicatedPlan(
                 version=version, replicas=tuple(plans),
@@ -661,7 +651,6 @@ class Planner:
             capacity=spec.capacity, version=version, max_parts=comm.n,
             dispatcher=0,
             include_dispatcher=True,
-            compression_ratio=spec.compression_ratio,
         )
         issues = plan.slo_issues(spec)
         if issues:

@@ -2,7 +2,7 @@
 
 A spec names *what* to deploy (a ``LayerGraph`` or a model-zoo name), *where*
 (a ``ClusterSpec``: explicit ``CommGraph`` or a seeded random wireless
-cluster), *how* (strategy names from the registry, compression, bandwidth
+cluster), *how* (strategy names from the registry, link codecs, bandwidth
 classes), and *how well* (optional SLOs).  ``validate()`` returns structured
 ``SpecIssue``s explaining *why* a spec is unusable -- an unknown strategy
 name, a single layer that exceeds node capacity, a model that cannot fit the
@@ -268,8 +268,6 @@ class DeploymentSpec:
     capacity:
         per-node memory cap handed to the partitioner; ``None`` uses the
         cluster's max node capacity (the dispatcher's historical default).
-    compression_ratio:
-        boundary compression (paper: ZFP/LZ4; ours: int8 analogue).
     codec:
         inter-stage transfer codec, by registry name (``identity`` /
         ``fp16`` / ``int8`` / ``topk-sparse``; see
@@ -297,26 +295,22 @@ class DeploymentSpec:
         model's own executor (``demo_mlp``) or a pass-through executor
         (timing-only simulation).
     microbatch:
-        serving-loop admission batch size.
-    serving:
-        ``"pipelined"`` (default) serves through the discrete-event engine
-        (``cluster.engine.PipelinedServingLoop``: every partition advances
-        independently, throughput = bottleneck rate); ``"sync"`` uses the
-        synchronous baseline loop (one microbatch through the whole chain
-        per round, throughput = 1 / end-to-end time).
+        serving-loop admission batch size.  Requests are served by the
+        discrete-event engine (``cluster.engine.PipelinedServingLoop``:
+        every partition advances independently, throughput = bottleneck
+        rate).
     queue_depth:
-        pipelined mode only: bound on each stage's in-queue (backpressure).
+        bound on each stage's in-queue (backpressure).
     replicas:
         pipeline replica count.  ``1`` (default) plans one pipeline over the
         whole cluster; an int R partitions the hosting nodes into R disjoint
         sub-clusters and serves one data-parallel pipeline per sub-cluster
         behind a cluster-wide router; ``"auto"`` picks the R maximizing the
-        summed predicted throughput.  Replicated serving always uses the
-        pipelined engine.
+        summed predicted throughput.
     max_batch:
         continuous batching: coalesce up to this many queued requests into
-        one microbatch per admission (pipelined engine only).  ``None``
-        keeps the fixed ``microbatch`` admission target.
+        one microbatch per admission.  ``None`` keeps the fixed
+        ``microbatch`` admission target.
     admission_depth:
         open-loop admission bound: arrivals past this queue depth are
         rejected (load shedding) instead of queueing without bound.
@@ -353,7 +347,6 @@ class DeploymentSpec:
     model: Any
     cluster: Any
     capacity: float | None = None
-    compression_ratio: float = 1.0
     codec: str | None = None
     accuracy_tolerance: float | None = None
     partitioner: str | None = None
@@ -365,7 +358,6 @@ class DeploymentSpec:
     min_throughput: float | None = None
     executor_for_version: Callable | None = None
     microbatch: int = 4
-    serving: str = "pipelined"
     queue_depth: int = 2
     replicas: int | str = 1
     max_batch: int | None = None
@@ -466,10 +458,6 @@ class DeploymentSpec:
             issues.extend(cluster_issues)
             cluster_ok = not cluster_issues
 
-        if self.compression_ratio <= 0:
-            issues.append(SpecIssue("bad_compression",
-                                    "compression_ratio must be > 0"))
-
         # transfer codec + per-link accuracy tolerance
         from repro.dataplane import AUTO, UnknownCodecError, get_codec
 
@@ -496,15 +484,9 @@ class DeploymentSpec:
                     f"use codec='auto' to let the planner pick within it",
                 ))
 
-        if self.serving not in ("pipelined", "sync"):
-            issues.append(SpecIssue(
-                "bad_serving",
-                f"serving must be 'pipelined' or 'sync', got {self.serving!r}",
-            ))
-        if self.queue_depth < 1:
-            issues.append(SpecIssue("bad_serving", "queue_depth must be >= 1"))
-
         # heavy-traffic serving knobs
+        if self.queue_depth < 1:
+            issues.append(SpecIssue("bad_batching", "queue_depth must be >= 1"))
         if self.max_batch is not None and (
             not isinstance(self.max_batch, int)
             or isinstance(self.max_batch, bool) or self.max_batch < 1
@@ -547,12 +529,6 @@ class DeploymentSpec:
                 ))
             else:
                 issues.extend(self.arrival.validate())
-            if self.serving == "sync":
-                issues.append(SpecIssue(
-                    "bad_serving",
-                    "open-loop arrivals serve through the pipelined engine "
-                    "(timestamped admission); serving='sync' is closed-loop",
-                ))
         if self.autoscale is not None:
             if not isinstance(self.autoscale, AutoscaleSpec):
                 issues.append(SpecIssue(
@@ -562,12 +538,6 @@ class DeploymentSpec:
                 ))
             else:
                 issues.extend(self.autoscale.validate())
-            if self.serving == "sync":
-                issues.append(SpecIssue(
-                    "bad_autoscale",
-                    "autoscaling serves through the replicated pipelined "
-                    "engine; serving='sync' supports only a fixed pipeline",
-                ))
             if self.replicas != 1:
                 issues.append(SpecIssue(
                     "bad_autoscale",
@@ -596,12 +566,6 @@ class DeploymentSpec:
             issues.append(SpecIssue(
                 "bad_replicas",
                 f"replicas must be an int >= 1 or 'auto', got {self.replicas!r}",
-            ))
-        elif self.replicas != 1 and self.serving == "sync":
-            issues.append(SpecIssue(
-                "bad_replicas",
-                "replica sets serve through the pipelined engine; "
-                "serving='sync' supports only replicas=1",
             ))
 
         # capacity feasibility: report WHY, naming the offending layer

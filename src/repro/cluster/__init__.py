@@ -6,7 +6,7 @@ from repro.cluster.controlplane import (
     ReconcileAction,
     ReplicaSet,
 )
-from repro.cluster.dispatcher import DeploymentPlan, Dispatcher, PlacementInfeasible
+from repro.cluster.dispatcher import Dispatcher, PlacementInfeasible
 from repro.cluster.events import (
     ClusterEvent,
     LinkDegraded,
@@ -27,12 +27,7 @@ from repro.cluster.lifecycle import (
     PipelineDegraded,
     Pod,
 )
-from repro.cluster.serving import (
-    Request,
-    ServingLoop,
-    latency_report,
-    latency_stats,
-)
+from repro.cluster.serving import Request, latency_report, latency_stats
 from repro.cluster.store import ArtifactStore
 from repro.cluster.watch import ModelWatcher
 
@@ -41,7 +36,6 @@ __all__ = [
     "Autoscaler",
     "ClusterEvent",
     "ControlPlane",
-    "DeploymentPlan",
     "DesiredState",
     "Dispatcher",
     "EdgeCluster",
@@ -62,7 +56,6 @@ __all__ = [
     "ReplicatedServingLoop",
     "Request",
     "ScaleEvent",
-    "ServingLoop",
     "StageState",
     "VersionBumped",
     "latency_report",

@@ -5,7 +5,7 @@ The one-shot ``configure -> deploy`` calls in ``dispatcher.py`` are the
 under churn.  A ``ControlPlane`` owns
 
   * a **desired state** (``DesiredState``): model version + layer graph,
-    per-node capacity, boundary compression,
+    per-node capacity,
   * an **observed state** (``ObservedState``): deployed version, restart
     generation, pod path, node health, leader, measured bottleneck,
 
@@ -48,7 +48,9 @@ from repro.cluster.events import (
 )
 from repro.cluster.lifecycle import EdgeCluster, ExecutorFn, InferencePipeline
 from repro.cluster.store import ArtifactStore
+from repro.core.bottleneck import stage_times
 from repro.core.graph import LayerGraph
+from repro.dataplane import Hop
 
 
 @dataclasses.dataclass
@@ -58,7 +60,6 @@ class DesiredState:
     version: int
     graph: LayerGraph
     capacity: float | None = None
-    compression_ratio: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,7 +108,6 @@ class ControlPlane:
         *,
         planner: Planner | None = None,
         capacity: float | None = None,
-        compression_ratio: float = 1.0,
         n_classes: int | None = UNSET,
         link_tolerance: float = 1.25,
         seed: int = 0,
@@ -135,7 +135,6 @@ class ControlPlane:
         self.scoped_recovery = scoped_recovery
         self.recovery_width = recovery_width
         self._default_capacity = capacity
-        self._default_compression = compression_ratio
         self.desired: DesiredState | None = None
         self.pipeline: InferencePipeline | None = None
         self.generation = 0
@@ -152,34 +151,27 @@ class ControlPlane:
         version: int,
         *,
         capacity: float | None = None,
-        compression_ratio: float | None = None,
     ) -> InferencePipeline:
         """Initial convergence: elect, probe, configure, deploy (Sec. 2.1-2.2).
 
-        ``capacity`` / ``compression_ratio`` default to the constructor's
-        values; pass them here only to override per-bootstrap.
+        ``capacity`` defaults to the constructor's value; pass it here only
+        to override per-bootstrap.
         """
         if capacity is None:
             capacity = self._default_capacity
-        if compression_ratio is None:
-            compression_ratio = self._default_compression
         graph = self.graph_for_version(version)
-        self.desired = DesiredState(version, graph, capacity, compression_ratio)
+        self.desired = DesiredState(version, graph, capacity)
         self.dispatcher.elect_leader()
         self.dispatcher.probe_bandwidths()
         plan = self._configure(graph, version)
         self.pipeline = self.dispatcher.deploy(
-            plan, self.executor_for_version(version),
-            compression_ratio=compression_ratio,
-        )
+            plan, self.executor_for_version(version))
         self.store.publish(version)
         return self.pipeline
 
     def _configure(self, graph: LayerGraph, version: int) -> Plan:
         plan = self.dispatcher.configure(
-            graph, version, capacity=self.desired.capacity,
-            compression_ratio=self.desired.compression_ratio,
-        )
+            graph, version, capacity=self.desired.capacity)
         if not plan.feasible:
             raise PlacementInfeasible(f"version {version} does not fit the cluster")
         return plan
@@ -218,9 +210,7 @@ class ControlPlane:
         for pod in self.pipeline.pods:  # stop the old inference pods
             pod.alive = False
         self.pipeline = self.dispatcher.deploy(
-            plan, self.executor_for_version(self.desired.version),
-            compression_ratio=self.desired.compression_ratio,
-        )
+            plan, self.executor_for_version(self.desired.version))
         self.history.append(ReconcileAction(
             None, "redeploy",
             f"replan with {dict(plan.strategies)}",
@@ -327,9 +317,7 @@ class ControlPlane:
             pod.alive = False
         # reuse the probed bandwidths: no re-election, no re-probe
         self.pipeline = self.dispatcher.deploy(
-            plan, self.executor_for_version(event.version),
-            compression_ratio=self.desired.compression_ratio,
-        )
+            plan, self.executor_for_version(event.version))
         if self.store.current_version() < event.version:
             self.store.publish(event.version)
         return ReconcileAction(
@@ -383,9 +371,7 @@ class ControlPlane:
             pod.alive = False
         self.generation += 1
         self.pipeline = self.dispatcher.deploy(
-            plan, self.executor_for_version(self.desired.version),
-            compression_ratio=self.desired.compression_ratio,
-        )
+            plan, self.executor_for_version(self.desired.version))
         return ReconcileAction(
             event, "restart", f"full restart (gen {self.generation}) after node {joined} joined"
         )
@@ -446,7 +432,7 @@ class ControlPlane:
                 dict(self.dispatcher.last_recovery))
 
     def _current_bottleneck(self) -> float:
-        """Max link time of the deployed path on the TRUE bandwidths,
+        """Max wire time of the deployed path on the TRUE bandwidths,
         including the dispatcher round-trip (input to the first partition,
         output from the last) when the leader is not colocated.
 
@@ -456,25 +442,35 @@ class ControlPlane:
         ``1 / bottleneck_latency`` when a leader link is the slowest edge.
         This metric matches the *placement objective* (which also scores
         in_bytes/out_bytes/dispatcher), not the serving clock."""
-        pipe = self.pipeline
-        lat = 0.0
-        for i in range(len(pipe.pods) - 1):
-            bw = self.cluster.true_bandwidth(
-                pipe.pods[i].node_id, pipe.pods[i + 1].node_id
-            )
-            bytes_ = pipe.wire_bytes(i)  # compression_ratio + hop codec
-            lat = max(lat, float("inf") if bw <= 0 else bytes_ / bw)
         graph = self.desired.graph if self.desired else None
-        lead = self.dispatcher.leader
-        if graph is not None and lead is not None:
-            for bytes_, node in (
-                (graph.in_bytes, pipe.pods[0].node_id),
-                (graph.layers[-1].out_bytes, pipe.pods[-1].node_id),
-            ):
-                if bytes_ > 0 and node != lead:
-                    bw = self.cluster.true_bandwidth(lead, node)
-                    lat = max(lat, float("inf") if bw <= 0 else bytes_ / bw)
+        links = self.pipeline.hops(
+            dispatcher=self.dispatcher.leader,
+            in_bytes=graph.in_bytes if graph is not None else 0.0,
+            out_bytes=graph.layers[-1].out_bytes if graph is not None else 0.0,
+        )
+        lat = 0.0
+        for link in links:
+            if link.codec is None:
+                continue  # nothing crosses a wire
+            bw = self.cluster.true_bandwidth(link.src, link.dst)
+            lat = max(lat, float("inf") if bw <= 0 else link.wire_bytes / bw)
         return lat
+
+    def pipeline_times(self) -> tuple[list[float], list[Hop]]:
+        """The deployed pipeline's stage service times and hop records, on
+        the probed bandwidths (the true ones before the first probe), with
+        the dispatcher round-trip -- the one timing the serving engine binds
+        its clock to and the critical-path analysis pins against."""
+        pipe, disp = self.pipeline, self.dispatcher
+        graph = self.desired.graph
+        comm = disp.probed if disp.probed is not None else self.cluster.comm
+        links = pipe.hops(
+            comm.bw, dispatcher=disp.leader,
+            in_bytes=graph.in_bytes, out_bytes=graph.layers[-1].out_bytes,
+        )
+        flops = [n.flops_per_s for n in self.cluster.nodes]
+        return stage_times([p.partition for p in pipe.pods], pipe.path(),
+                           flops), links
 
     # -- observation ---------------------------------------------------------
     def observed(self) -> ObservedState:

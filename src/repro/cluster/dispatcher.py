@@ -27,12 +27,10 @@ import numpy as np
 from repro.api.planner import Plan, Planner
 from repro.cluster.lifecycle import EdgeCluster, InferencePipeline, Pod
 from repro.cluster.store import ArtifactStore
+from repro.core.bottleneck import evaluate_pipeline
 from repro.core.graph import LayerGraph
 from repro.core.placement import CommGraph
-
-# ``DeploymentPlan`` was the dispatcher's own plan type before the
-# declarative API subsumed it; the alias keeps old imports working.
-DeploymentPlan = Plan
+from repro.dataplane import hop_bytes
 
 # sentinel: n_classes=None legitimately means "unquantized", so "not given"
 # needs its own marker to detect a planner/n_classes conflict
@@ -180,7 +178,6 @@ class Dispatcher:
         *,
         capacity: float | None = None,
         include_dispatcher: bool = True,
-        compression_ratio: float = 1.0,
     ) -> Plan:
         if self.leader is None:
             self.elect_leader()
@@ -195,20 +192,13 @@ class Dispatcher:
             include_dispatcher=include_dispatcher,
             dispatcher=self.leader if include_dispatcher else None,
             device_flops=self.node_flops(),
-            compression_ratio=compression_ratio,
         )
         if plan.feasible:
             self.last_plan = plan
             self.store.put_json(version, "plan", plan.summary())
         return plan
 
-    def deploy(
-        self,
-        plan: Plan,
-        executor: Callable,
-        *,
-        compression_ratio: float = 1.0,
-    ) -> InferencePipeline:
+    def deploy(self, plan: Plan, executor: Callable) -> InferencePipeline:
         if not plan.feasible:
             raise PlacementInfeasible("cannot deploy infeasible plan")
         pods = [
@@ -220,7 +210,6 @@ class Dispatcher:
             pods,
             executor,
             boundary_bytes=list(plan.partition.boundaries),
-            compression_ratio=compression_ratio,
             link_codecs=list(plan.codecs) if plan.codecs else None,
             execution=self.execution,
         )
@@ -322,12 +311,10 @@ class Dispatcher:
             self.last_recovery["fallback"] = "reconfigure"
             self.recovery_log.append(dict(self.last_recovery))
             # partitions no longer fit the surviving nodes: full reconfigure
-            plan = self.configure(graph, version, capacity=capacity,
-                                  compression_ratio=pipeline.compression_ratio)
+            plan = self.configure(graph, version, capacity=capacity)
             if not plan.feasible:
                 raise PlacementInfeasible("cluster too degraded to host the model")
-            return self.deploy(plan, pipeline.executor,
-                               compression_ratio=pipeline.compression_ratio)
+            return self.deploy(plan, pipeline.executor)
         for pod, node in zip(pipeline.pods, place.path):
             if not pod.alive or not self.cluster.nodes[pod.node_id].healthy:
                 pod.restart_on(node)
@@ -336,25 +323,21 @@ class Dispatcher:
         # joint codec x placement: the links changed, so the codec-per-link
         # assignment is re-solved for the new path and follows the pipeline
         codecs = self.planner.assign_codecs(
-            [graph.in_bytes, *pipeline.boundary_bytes,
-             graph.layers[-1].out_bytes],
+            hop_bytes(pipeline.boundary_bytes, graph.in_bytes,
+                      graph.layers[-1].out_bytes),
             place.path, comm.bw,
             dispatcher=self.leader, flops_per_node=self.node_flops(),
-            compression_ratio=pipeline.compression_ratio,
         )
         pipeline.link_codecs = list(codecs)
         # the plan record must track what is actually deployed: same
         # partitions, new placement, metrics re-scored on the re-probed comm
         if self.last_plan is not None:
-            from repro.core.bottleneck import evaluate_pipeline
-
             metrics = evaluate_pipeline(
                 part, place.path, comm,
                 device_flops=self.node_flops(),
                 in_bytes=graph.in_bytes,
                 out_bytes=graph.layers[-1].out_bytes,
                 dispatcher=self.leader,
-                compression_ratio=pipeline.compression_ratio,
                 codecs=codecs,
             )
             self.last_plan = dataclasses.replace(

@@ -4,11 +4,11 @@ SEIFER's headline claim -- ~200% more inference throughput from partitioning
 across resource-constrained nodes -- rests on *pipeline parallelism*: each
 partition works on a different microbatch concurrently, so steady-state
 throughput equals the bottleneck stage's rate, independent of pipeline depth
-(same model as DEFER and the companion placement paper).  The synchronous
-``ServingLoop`` pushes one microbatch through the whole chain per round and
-therefore pays the *sum* of stage times; this module replaces it with a
+(same model as DEFER and the companion placement paper).  Pushing one
+microbatch through the whole chain at a time would pay the *sum* of stage
+and link times (the plan's ``end_to_end_latency``); this module is a
 virtual-clock scheduler in which every placed partition advances
-independently:
+independently, and the only serving engine ``deploy()`` builds:
 
   * **virtual clock** -- ``clock_s`` advances to the earliest pending event
     (a compute or a transfer finishing); nothing is wall-clock timed.
@@ -18,11 +18,11 @@ independently:
     (backpressure), bounding memory everywhere.
   * **serial resources** -- each stage computes one microbatch at a time
     (service time = ``partition.flops / node.flops_per_s``) and each link
-    carries one transfer at a time (``boundary_bytes / probed_bandwidth``,
-    compression-adjusted), including the dispatcher's input/output hops.
-    Steady-state throughput is therefore ``1 / max(stage, link times)`` --
-    exactly what ``Planner`` predicts via the shared
-    ``core.bottleneck.service_times`` model.
+    carries one transfer at a time (the ``dataplane.hops`` record's window:
+    codec encode + wire bytes / probed bandwidth + decode), including the
+    dispatcher's input/output hops.  Steady-state throughput is therefore
+    ``1 / max(stage, link times)`` -- exactly what ``Planner`` predicts
+    from the same hop records (``core.bottleneck.service_times``).
   * **in-flight tracking** -- every admitted request lives in exactly one
     place: the admission queue, one in-flight microbatch, ``completed``, or
     ``failed``.  When reconciliation disturbs the pipeline, microbatches
@@ -37,10 +37,9 @@ Besides the virtual clock, ``PipelinedServingLoop`` opens wall-clock
 profiler capture shows what the host did next to the device's ops.  They
 record only inside a profiler session and never wait on the device.
 
-The engine exposes the same surface as ``ServingLoop`` (``submit`` /
-``step`` / ``drain`` / ``metrics`` / ``backlog``), so ``Deployment`` and the
-benchmarks can switch between the honest synchronous baseline and the
-pipelined engine with one spec field.
+``ReplicatedServingLoop`` runs one engine per replica behind a router with
+the same ``submit`` / ``step`` / ``drain`` / ``metrics`` / ``backlog``
+surface, so ``Deployment`` and the benchmarks drive either alike.
 """
 
 from __future__ import annotations
@@ -58,8 +57,7 @@ from repro.cluster.dispatcher import PlacementInfeasible
 from repro.cluster.events import NodeFailed
 from repro.cluster.lifecycle import Pod
 from repro.cluster.serving import Request, latency_report, normalize_metrics
-from repro.core.bottleneck import service_times
-from repro.obs.trace import split_hop, split_window
+from repro.obs.trace import split_window
 
 _ALL = "all"  # sentinel: every stage is affected (version bump, restart)
 
@@ -108,10 +106,8 @@ class StageState:
 class PipelinedServingLoop:
     """Discrete-event pipelined serving over a ``ControlPlane``.
 
-    Drop-in for ``ServingLoop``: same constructor shape, same
-    ``submit``/``step``/``drain``/``metrics`` surface, same recovery
-    semantics (reconcile pending events before advancing; a non-trivial
-    reconcile costs ``recovery_penalty_s`` of virtual time).
+    Recovery semantics: pending events are reconciled before advancing, and
+    a non-trivial reconcile costs ``recovery_penalty_s`` of virtual time.
     """
 
     def __init__(
@@ -165,12 +161,9 @@ class PipelinedServingLoop:
         self._next_mb = 0
         self._inflight: list[Microbatch] = []
         self._stages: list[StageState] = []
+        self._hops: list = []  # dataplane.hops.Hop per hop, len k+1
         self._link_s: list[float] = []  # per-hop transfer time, len k+1
         self._links_busy: list[Microbatch | None] = []
-        self._link_codecs: list = []  # Codec per hop (None = raw / no wire)
-        self._link_parts: list = []  # (encode_s, wire_s, decode_s) per hop
-        self._link_raw: list[float] = []  # raw boundary bytes per hop
-        self._link_wire: list[float] = []  # on-wire bytes per hop
         self._link_busy_s: list[float] = []  # time each link spent occupied
         self._link_xfers: list[int] = []  # completed transfers per hop
         self._mb_completed = 0
@@ -346,18 +339,19 @@ class PipelinedServingLoop:
             "links": [
                 {
                     "hop": h,
-                    "codec": codec.name if codec is not None else "identity",
-                    "raw_bytes": self._link_raw[h],
-                    "wire_bytes": self._link_wire[h],
+                    "codec": (link.codec.name if link.codec is not None
+                              else "identity"),
+                    "raw_bytes": link.raw_bytes,
+                    "wire_bytes": link.wire_bytes,
                     "compression_x": (
-                        self._link_raw[h] / self._link_wire[h]
-                        if self._link_wire[h] > 0 else 1.0
+                        link.raw_bytes / link.wire_bytes
+                        if link.wire_bytes > 0 else 1.0
                     ),
                     "link_s": self._link_s[h],
                     "utilization": self._link_busy_s[h] / t if t > 0 else 0.0,
                     "transfers": self._link_xfers[h],
                 }
-                for h, codec in enumerate(self._link_codecs)
+                for h, link in enumerate(self._hops)
             ],
             "stages": [
                 {
@@ -437,61 +431,20 @@ class PipelinedServingLoop:
         """
         control = self.control
         pipe = control.pipeline
-        disp = control.dispatcher
-        graph = control.desired.graph
         if self.tracer is not None:
             # close every traced batch's open span on the OLD hop/stage
-            # geometry (the decomposition tables are about to be rebuilt);
+            # geometry (the hop records are about to be rebuilt);
             # re-seated batches reopen below, requeued ones restart from
             # admission
             for mb in self._inflight:
                 if mb.traced:
                     self._trace_close(mb, self.clock_s)
-        comm = disp.probed if disp.probed is not None else control.cluster.comm
-        path = [p.node_id for p in pipe.pods]
-        parts = [p.partition for p in pipe.pods]
-        codecs = [pipe.hop_codec(h) for h in range(len(path) + 1)]
-        compute_s, link_s = service_times(
-            parts, path, comm.bw,
-            flops_per_node=[n.flops_per_s for n in control.cluster.nodes],
-            in_bytes=graph.in_bytes,
-            out_bytes=graph.layers[-1].out_bytes,
-            dispatcher=disp.leader,
-            compression_ratio=pipe.compression_ratio,
-            codecs=None if pipe.link_codecs is None else pipe.link_codecs,
-        )
-        k = len(path)
-        # per-hop byte model for the link report: raw boundary bytes (after
-        # the legacy compression knob) vs what the codec puts on the wire;
-        # a hop with colocated endpoints or zero bytes carries no codec
-        hop_bytes = [graph.in_bytes, *pipe.boundary_bytes,
-                     graph.layers[-1].out_bytes]
-        ends = [(disp.leader, path[0] if path else None)]
-        ends += [(path[i], path[i + 1]) for i in range(k - 1)]
-        ends += [(path[-1] if path else None, disp.leader)]
-        self._link_codecs, self._link_raw, self._link_wire = [], [], []
-        for h in range(k + 1):
-            raw = float(hop_bytes[h]) / pipe.compression_ratio
-            a, b = ends[h]
-            active = raw > 0 and a is not None and b is not None and a != b
-            codec = codecs[h] if active else None
-            self._link_codecs.append(codec)
-            self._link_raw.append(raw if active else 0.0)
-            self._link_wire.append(
-                codec.wire_bytes(raw) if codec is not None
-                else (raw if active else 0.0))
-        # analytic encode/wire/decode decomposition of each hop window, on
-        # the same codec cost model link_s itself was built from -- the
-        # tracer tiles observed link windows with these proportions
-        flops = [n.flops_per_s for n in control.cluster.nodes]
-        self._link_parts = [
-            split_hop(
-                link_s[h], self._link_codecs[h], self._link_raw[h],
-                src_flops=flops[ends[h][0]] if ends[h][0] is not None else 0.0,
-                dst_flops=flops[ends[h][1]] if ends[h][1] is not None else 0.0,
-            )
-            for h in range(k + 1)
-        ]
+        # the hop records carry each link's codec (None: no wire), raw and
+        # wire bytes for the link report, and the encode/wire/decode split
+        # the tracer tiles observed link windows with
+        compute_s, self._hops = control.pipeline_times()
+        link_s = [link.seconds for link in self._hops]
+        k = len(pipe.pods)
         old_stages = self._stages
         carry_stats = len(old_stages) == k and affected is not _ALL
         self._stages = []
@@ -625,8 +578,8 @@ class PipelinedServingLoop:
         if t == float("inf"):
             # every pending event is a transfer on a dead link: it can never
             # finish, so retry the riders instead of hanging callers that
-            # loop on backlog.  attempts bound the retries (-> failed), the
-            # sync loop's liveness guarantee.
+            # loop on backlog.  attempts bound the retries (-> failed), so
+            # every request ends completed or failed.
             self._requeue_stalled([m for m in pend if m.ready_at == float("inf")])
             self._schedule()
             return True
@@ -664,7 +617,7 @@ class PipelinedServingLoop:
                 self._link_xfers[idx] += 1
                 if mb.traced:
                     self._trace_close(mb, self.clock_s)  # encode/wire/decode
-                codec = self._link_codecs[idx] if idx < len(self._link_codecs) else None
+                codec = self._hops[idx].codec if idx < len(self._hops) else None
                 if codec is not None:
                     executor = self.control.pipeline.executor
                     fused = (idx != k and codec.name
@@ -876,10 +829,9 @@ class PipelinedServingLoop:
         emit = self.tracer.record_many
         gen = self.control.generation
         if name == "xfer":
-            parts = (self._link_parts[idx] if idx < len(self._link_parts)
-                     else (0.0, t1 - t0, 0.0))
-            codec = (self._link_codecs[idx]
-                     if idx < len(self._link_codecs) else None)
+            link = self._hops[idx] if idx < len(self._hops) else None
+            parts = link.parts if link is not None else (0.0, t1 - t0, 0.0)
+            codec = link.codec if link is not None else None
             cname = codec.name if codec is not None else None
             for phase, a, b in split_window(t0, t1, parts):
                 emit(mb.traced, phase, a, b, hop=idx, codec=cname,

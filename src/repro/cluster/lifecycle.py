@@ -3,7 +3,7 @@
 ``EdgeCluster`` holds nodes and the true link bandwidths; ``Pod``s host one
 partition each and forward intermediate activations to the next pod --
 latency is simulated from bytes / bandwidth (the paper's FIFO+TCP transport)
-with optional boundary int8 compression (the ZFP/LZ4 analogue).  Node
+with a transfer codec on each link (``repro.dataplane``).  Node
 failures mark pods dead; the dispatcher reschedules onto healthy nodes and
 pods re-instantiate their partition from the artifact store, exactly the
 SEIFER recovery path.
@@ -18,6 +18,8 @@ import numpy as np
 
 from repro.core.graph import Partition
 from repro.core.placement import CommGraph
+from repro.dataplane import Hop, build_hops, get_codec, hop_bytes
+from repro.dataplane.base import EncodedActivation
 
 
 @dataclasses.dataclass
@@ -154,7 +156,6 @@ class InferencePipeline:
         pods: Sequence[Pod],
         executor: ExecutorFn,
         boundary_bytes: Sequence[float],
-        compression_ratio: float = 1.0,
         link_codecs: Sequence[str] | None = None,
         execution=None,
     ):
@@ -162,8 +163,7 @@ class InferencePipeline:
         self.pods = list(pods)
         self.executor = executor
         self.boundary_bytes = list(boundary_bytes)
-        self.compression_ratio = compression_ratio
-        # transfer codec per hop (len k+1, service_times indexing); None =
+        # transfer codec per hop (len k+1, build_hops indexing); None =
         # all-identity (direct lifecycle construction, pre-dataplane tests)
         self.link_codecs = list(link_codecs) if link_codecs is not None else None
         # execution knob (repro.core.execution.ExecutionKnob | None):
@@ -179,8 +179,6 @@ class InferencePipeline:
         execution knob; the registry singletons stay untouched."""
         if self.link_codecs is None or not 0 <= h < len(self.link_codecs):
             return None
-        from repro.dataplane import get_codec
-
         codec = get_codec(self.link_codecs[h])
         if (codec is not None and self.execution is not None
                 and getattr(self.execution, "use_pallas", False)
@@ -191,12 +189,26 @@ class InferencePipeline:
             )
         return codec
 
+    def hops(self, bw=None, *, dispatcher: int | None = None,
+             in_bytes: float = 0.0, out_bytes: float = 0.0) -> list[Hop]:
+        """The ``dataplane.hops`` record of each hop of this pipeline, on
+        ``bw`` (default: the cluster's true bandwidths).  Without a
+        ``dispatcher`` only the pod-to-pod hops carry bytes."""
+        codecs = None
+        if self.link_codecs is not None:
+            codecs = [self.hop_codec(h) for h in range(len(self.link_codecs))]
+        return build_hops(
+            hop_bytes(self.boundary_bytes, in_bytes, out_bytes),
+            self.path(), self.cluster.comm.bw if bw is None else bw,
+            dispatcher=dispatcher,
+            flops_per_node=[n.flops_per_s for n in self.cluster.nodes],
+            codecs=codecs,
+        )
+
     def wire_bytes(self, boundary_idx: int) -> float:
         """On-wire bytes of partition boundary ``boundary_idx`` (hop
-        ``boundary_idx + 1``) after compression_ratio and the hop codec."""
-        raw = self.boundary_bytes[boundary_idx] / self.compression_ratio
-        codec = self.hop_codec(boundary_idx + 1)
-        return codec.wire_bytes(raw) if codec is not None else raw
+        ``boundary_idx + 1``) under the hop's codec."""
+        return self.hops()[boundary_idx + 1].wire_bytes
 
     def path(self) -> list[int]:
         return [p.node_id for p in self.pods]
@@ -210,6 +222,7 @@ class InferencePipeline:
         """One inference through the chain; raises if a pod is dead."""
         if not self.healthy():
             raise PipelineDegraded("pipeline degraded: dead pod or failed node")
+        links = self.hops()
         compute_s, link_s = [], []
         for idx, pod in enumerate(self.pods):
             x = self.executor(pod.partition.start, pod.partition.stop, x)
@@ -218,18 +231,16 @@ class InferencePipeline:
                 pod.partition.flops / node.flops_per_s if node.flops_per_s else 0.0
             )
             if idx < len(self.pods) - 1:
+                link = links[idx + 1]
                 bw = self.cluster.true_bandwidth(
                     pod.node_id, self.pods[idx + 1].node_id
                 )
-                bytes_ = self.wire_bytes(idx)
-                link_s.append(float("inf") if bw <= 0 else bytes_ / bw)
-                codec = self.hop_codec(idx + 1)
-                if codec is not None and pod.node_id != self.pods[idx + 1].node_id:
+                link_s.append(float("inf") if bw <= 0 else link.wire_bytes / bw)
+                codec = link.codec
+                if codec is not None:
                     if codec.name in getattr(self.executor, "fused_codecs", ()):
                         # the receiving stage decodes inside its first op
                         # (fused dequant-matmul): hand over the wire payload
-                        from repro.dataplane.base import EncodedActivation
-
                         x = EncodedActivation(codec, codec.encode(x))
                     else:
                         # the receiver sees the decoded payload: lossy codecs

@@ -9,7 +9,7 @@ would close that loop into a circular import.
 """
 
 _SUBMODULE_EXPORTS = {
-    "bottleneck": ("PipelineMetrics", "evaluate_pipeline", "link_latencies"),
+    "bottleneck": ("PipelineMetrics", "evaluate_pipeline"),
     "graph": (
         "Layer",
         "LayerGraph",

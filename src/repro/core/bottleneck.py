@@ -6,6 +6,10 @@ its reciprocal.  The extended metric additionally accounts for per-stage
 compute time (used when mapping placements onto TPU pods, where stage compute
 can dominate the link): steady-state pipeline period = max over all stage
 compute times and link latencies.
+
+What each link carries and costs (endpoints, bytes, codec, window) is the
+hop model of ``repro.dataplane.hops``; this module adds the stage times and
+the pipeline-level scores on top of it.
 """
 
 from __future__ import annotations
@@ -15,8 +19,9 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core.graph import Partition
+from repro.core.graph import Partition, boundary_bytes
 from repro.core.placement import CommGraph
+from repro.dataplane.hops import build_hops, hop_bytes, node_flops
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,26 +33,18 @@ class PipelineMetrics:
     effective_throughput: float  # 1 / pipeline_period (extended)
 
 
-def link_latencies(
-    boundaries: Sequence[float], path: Sequence[int], comm: CommGraph
+def stage_times(
+    partitions: Sequence[Partition],
+    path: Sequence[int],
+    flops_per_node: float | Sequence[float] | None = None,
 ) -> list[float]:
-    out = []
-    for i, w in enumerate(boundaries):
-        b = comm.bw[path[i], path[i + 1]]
-        out.append(float("inf") if b <= 0 else w / b)
-    return out
-
-
-def node_flops(flops_per_node, node: int | None) -> float:
-    """Resolve a scalar-or-per-node flops model for one node (0 = unmodelled).
-
-    The single dispatch point for ``flops_per_node``: ``service_times`` and
-    the data plane's codec cost model both price compute through it."""
-    if flops_per_node is None or node is None:
-        return 0.0
-    if np.isscalar(flops_per_node):
-        return float(flops_per_node)
-    return float(flops_per_node[node])
+    """Stage i's service time, ``partition.flops / flops_per_node[path[i]]``
+    (0 when flops are unmodelled)."""
+    compute = []
+    for part, node in zip(partitions, path):
+        f = node_flops(flops_per_node, node)
+        compute.append(part.flops / f if f > 0 else 0.0)
+    return compute
 
 
 def service_times(
@@ -59,69 +56,29 @@ def service_times(
     in_bytes: float = 0.0,
     out_bytes: float = 0.0,
     dispatcher: int | None = None,
-    compression_ratio: float = 1.0,
     codecs: Sequence | None = None,
 ) -> tuple[list[float], list[float]]:
     """The single timing model shared by the discrete-event serving engine,
     the planner's prediction, and the TPU pipeline planner.
 
-    Returns ``(compute_s, link_s)``:
-
-      * ``compute_s[i]`` -- stage i's service time, ``partition.flops /
-        flops_per_node[path[i]]`` (0 when flops are unmodelled),
-      * ``link_s`` -- one entry per *hop*, ``len(path) + 1`` long:
-        ``link_s[0]`` is the dispatcher -> first-stage input transfer,
-        ``link_s[h]`` (1 <= h <= k-1) is the stage h-1 -> stage h boundary,
-        ``link_s[k]`` is the last-stage -> dispatcher output transfer.
-        Colocated endpoints (or zero bytes, or no dispatcher) cost 0.
-
-    ``codecs`` (one ``repro.dataplane.Codec`` or registered name per hop)
-    puts a transfer codec on each link: the hop's serial window is then
-    charged ``codec_encode (sender flops) + wire_bytes / bandwidth +
-    codec_decode (receiver flops)`` -- compressed bytes ride the wire, and
-    the codec's compute rides the link window it serializes.  ``None``
-    keeps every hop raw.  The legacy ``compression_ratio`` divides bytes
-    *before* the codec sees them (the knobs compose; both default off).
+    Returns ``(compute_s, link_s)``: ``stage_times`` per stage, and the
+    ``seconds`` of each ``dataplane.hops.build_hops`` record, one per hop
+    (``len(path) + 1``; dispatcher -> first stage, each stage boundary,
+    last stage -> dispatcher).  Colocated endpoints (or zero bytes, or no
+    dispatcher) cost 0; ``codecs`` (one per hop, ``None`` = identity)
+    charges each link its codec's ``encode + wire + decode`` window.
 
     The pipeline's steady-state period is ``max(compute_s + link_s)`` --
     every stage and every link is a serial resource, so the bottleneck one
     sets the cadence once the pipe is full.
     """
-    if codecs is not None:
-        from repro.dataplane import resolve_codecs
-
-        codecs = resolve_codecs(codecs)
-        if len(codecs) != len(path) + 1:
-            raise ValueError(
-                f"expected {len(path) + 1} hop codecs, got {len(codecs)}")
-
-    def flops_at(node: int | None) -> float:
-        return node_flops(flops_per_node, node)
-
-    def hop(a: int | None, b: int | None, bytes_: float, h: int) -> float:
-        if bytes_ <= 0 or a is None or b is None or a == b:
-            return 0.0
-        rate = float(bw[a, b])
-        raw = bytes_ / compression_ratio
-        if codecs is None:
-            return float("inf") if rate <= 0 else raw / rate
-        from repro.dataplane import link_charge_s
-
-        return link_charge_s(
-            codecs[h], raw, rate,
-            src_flops=flops_at(a), dst_flops=flops_at(b),
-        )
-
-    compute = []
-    for part, node in zip(partitions, path):
-        f = flops_at(node)
-        compute.append(part.flops / f if f > 0 else 0.0)
-    links = [hop(dispatcher, path[0] if path else None, in_bytes, 0)]
-    for i in range(len(path) - 1):
-        links.append(
-            hop(path[i], path[i + 1], float(partitions[i].out_bytes), i + 1))
-    links.append(hop(path[-1] if path else None, dispatcher, out_bytes, len(path)))
-    return compute, links
+    links = build_hops(
+        hop_bytes(boundary_bytes(partitions), in_bytes, out_bytes),
+        path, bw, dispatcher=dispatcher, flops_per_node=flops_per_node,
+        codecs=codecs,
+    )
+    return (stage_times(partitions, path, flops_per_node),
+            [link.seconds for link in links])
 
 
 def evaluate_pipeline(
@@ -132,15 +89,13 @@ def evaluate_pipeline(
     in_bytes: float = 0.0,
     out_bytes: float = 0.0,
     dispatcher: int | None = None,
-    compression_ratio: float = 1.0,
     codecs: Sequence | None = None,
 ) -> PipelineMetrics:
     """Score a (partition, placement) pair.
 
-    ``compression_ratio`` models boundary compression (paper: ZFP/LZ4; ours:
-    blockwise int8): transferred bytes are divided by it.  ``codecs`` (one
-    per hop, see ``service_times``) charges each link with its transfer
-    codec's ``encode + compressed transfer + decode`` window.  ``in_bytes``
+    ``codecs`` (one per hop, see ``service_times``) charges each link with
+    its transfer codec's ``encode + compressed transfer + decode`` window
+    (paper: ZFP/LZ4; ours: the ``dataplane`` codecs).  ``in_bytes``
     / ``out_bytes`` charge the dispatcher round-trip hops when
     ``dispatcher`` is given (colocation costs nothing).
     """
@@ -152,7 +107,6 @@ def evaluate_pipeline(
         in_bytes=in_bytes if dispatcher is not None else 0.0,
         out_bytes=out_bytes if dispatcher is not None else 0.0,
         dispatcher=dispatcher,
-        compression_ratio=compression_ratio,
         codecs=codecs,
     )
     lats = [h for h in hops if h > 0]

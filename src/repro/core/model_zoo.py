@@ -5,8 +5,8 @@ models (the DEFER predecessor used VGG16/ResNet-family models).  We
 reconstruct their chain layer graphs from the published architectures:
 per-layer parameter counts and output activation shapes.  Parameters default
 to 1 byte each (the paper quantizes models with TFLite before deployment);
-activations default to 4 bytes (float), with an optional compression ratio
-applied by the caller (paper: ZFP/LZ4).
+activations default to 4 bytes (float); a deployment's link codec
+compresses them on the wire (paper: ZFP/LZ4).
 
 These graphs feed ``core.simulate`` and the Fig. 3 / throughput benchmarks.
 The assigned LM architectures export their own graphs via

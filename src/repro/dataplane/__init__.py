@@ -14,25 +14,30 @@ restores throughput.  This package is that lever as a subsystem:
   * ``codecs``   -- ``identity`` / ``fp16`` / ``int8`` (backed by the
     ``kernels/quantize`` Pallas stack, numpy twin off the Pallas path) /
     ``topk-sparse``;
+  * ``hops``     -- the hop model: ``build_hops`` turns a placed pipeline
+    into one ``Hop`` record per link (endpoints, raw and wire bytes, codec,
+    ``encode + transfer(compressed) + decode`` window), the records every
+    timing, codec choice and link report reads;
   * ``auto``     -- per-link codec selection under a per-link
     ``accuracy_tolerance``, used by the planner's joint codec x placement
     search and provably never worse than ``identity``.
 
 The codec names flow spec -> plan -> pipeline -> engine: the planner picks
-(or is told) a codec per link, ``core.bottleneck.service_times`` charges
-``encode + transfer(compressed) + decode`` to the link's serial window, and
-the serving engine applies the real transform to every microbatch crossing
-that link -- the first place the Pallas quantize kernel participates in the
-serving path.
+(or is told) a codec per link, ``build_hops`` charges the codec's window to
+the link, and the serving engine applies the real transform to every
+microbatch crossing that link -- the first place the Pallas quantize kernel
+participates in the serving path.
 """
 
-from repro.dataplane.auto import (
-    assign_link_codecs,
+from repro.dataplane.auto import assign_link_codecs, select_codec
+from repro.dataplane.base import Codec
+from repro.dataplane.hops import (
+    Hop,
+    build_hops,
+    hop_bytes,
     link_charge_s,
     resolve_codecs,
-    select_codec,
 )
-from repro.dataplane.base import Codec
 from repro.dataplane.registry import (
     AUTO,
     UnknownCodecError,
@@ -46,11 +51,14 @@ from repro.dataplane.registry import (
 __all__ = [
     "AUTO",
     "Codec",
+    "Hop",
     "UnknownCodecError",
     "assign_link_codecs",
+    "build_hops",
     "codec_table",
     "default_codec",
     "get_codec",
+    "hop_bytes",
     "link_charge_s",
     "list_codecs",
     "register_codec",

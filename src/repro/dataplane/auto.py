@@ -14,7 +14,7 @@ plan, and because every candidate's window is non-increasing in bandwidth,
 predicted throughput stays monotone in link bandwidth -- both properties are
 pinned by ``tests/test_dataplane_properties.py``.
 
-Hop indexing matches ``core.bottleneck.service_times``: hop 0 is the
+Hops are indexed and built by ``dataplane.hops.build_hops``: hop 0 is the
 dispatcher -> first-stage input, hop h (1 <= h <= k-1) the stage h-1 ->
 stage h boundary, hop k the last-stage -> dispatcher output.  The dispatcher
 round-trip hops always ride ``identity`` -- codecs compress *inter-stage*
@@ -27,33 +27,8 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core.bottleneck import node_flops
-from repro.dataplane.base import Codec
+from repro.dataplane.hops import build_hops, link_charge_s, node_flops
 from repro.dataplane.registry import AUTO, default_codec, get_codec, list_codecs
-
-
-def resolve_codecs(codecs) -> list[Codec] | None:
-    """Names-or-instances -> instances (None passes through)."""
-    if codecs is None:
-        return None
-    return [c if isinstance(c, Codec) else get_codec(c) for c in codecs]
-
-
-def link_charge_s(
-    codec: Codec,
-    nbytes: float,
-    bw: float,
-    *,
-    src_flops: float = 0.0,
-    dst_flops: float = 0.0,
-) -> float:
-    """The serial window one ``nbytes`` transfer occupies on the link."""
-    if nbytes <= 0:
-        return 0.0
-    wire = codec.wire_bytes(nbytes)
-    xfer = float("inf") if bw <= 0 else wire / bw
-    return codec.encode_cost_s(nbytes, src_flops) + xfer + \
-        codec.decode_cost_s(nbytes, dst_flops)
 
 
 def select_codec(
@@ -98,40 +73,30 @@ def assign_link_codecs(
     tolerance: float | None = None,
     flops_per_node=None,
     dispatcher: int | None = None,
-    compression_ratio: float = 1.0,
 ) -> tuple[str, ...]:
     """One codec name per hop (``len(path) + 1`` entries).
 
     ``codec`` is a registered name (every inter-stage hop uses it), ``"auto"``
     (per-hop optimum as above), or ``None`` (the registry default,
-    ``identity``).  ``hop_bytes`` are the *raw* boundary bytes in hop order;
-    the legacy ``compression_ratio`` is applied before the codec, matching
-    ``service_times``.
+    ``identity``).  ``hop_bytes`` are the *raw* boundary bytes in hop order.
     """
     k = len(path)
-    if len(hop_bytes) != k + 1:
-        raise ValueError(f"expected {k + 1} hop byte counts, got {len(hop_bytes)}")
+    links = build_hops(hop_bytes, path, bw, dispatcher=dispatcher)
     if codec is None:
         codec = default_codec()
     names: list[str] = []
-    for h in range(k + 1):
-        src = dispatcher if h == 0 else path[h - 1]
-        dst = dispatcher if h == k else path[h]
-        interior = 1 <= h <= k - 1
-        if not interior:
+    for h, link in enumerate(links):
+        if not 1 <= h <= k - 1:
             names.append(default_codec())  # dispatcher round-trip: raw
-            continue
-        if codec != AUTO:
+        elif codec != AUTO:
             names.append(codec)
-            continue
-        raw = float(hop_bytes[h]) / compression_ratio
-        if raw <= 0 or src is None or dst is None or src == dst:
+        elif link.codec is None:
             names.append(default_codec())  # nothing crosses a wire here
-            continue
-        names.append(select_codec(
-            raw, float(bw[src, dst]),
-            tolerance=tolerance,
-            src_flops=node_flops(flops_per_node, src),
-            dst_flops=node_flops(flops_per_node, dst),
-        ))
+        else:
+            names.append(select_codec(
+                link.raw_bytes, float(bw[link.src, link.dst]),
+                tolerance=tolerance,
+                src_flops=node_flops(flops_per_node, link.src),
+                dst_flops=node_flops(flops_per_node, link.dst),
+            ))
     return tuple(names)

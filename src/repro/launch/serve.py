@@ -66,7 +66,6 @@ def serve_edge(
     joint: str | None = None,
     capacity_frac: float = 1 / 3,
     width: int = 32,
-    serving: str = "pipelined",
     queue_depth: int = 2,
     replicas: int | str = 1,
     codec: str | None = None,
@@ -113,7 +112,6 @@ def serve_edge(
         accuracy_tolerance=tolerance,
         seed=seed,
         microbatch=4,
-        serving=serving,
         queue_depth=queue_depth,
         replicas=replicas,
         max_batch=max_batch,
@@ -129,12 +127,12 @@ def serve_edge(
     names = dict(d.plan.strategies)
     if d.replicated:
         sets = d.replicaset
-        print(f"edge serving [{names}, {serving}, x{sets.n_replicas} replicas]: "
+        print(f"edge serving [{names}, x{sets.n_replicas} replicas]: "
               f"groups {[sorted(g) for g in sets.groups]}, summed predicted "
               f"{d.plan.predicted_throughput:.1f} microbatch/s")
     else:
         obs = d.observed()
-        print(f"edge serving [{names}, {serving}]: {len(obs.path)} partitions on "
+        print(f"edge serving [{names}]: {len(obs.path)} partitions on "
               f"nodes {list(obs.path)}, bottleneck {obs.bottleneck_latency*1e3:.3f} ms, "
               f"predicted {d.plan.predicted_throughput:.1f} microbatch/s, "
               f"link codecs {list(d.plan.codecs)}")
@@ -331,12 +329,8 @@ def main() -> int:
                          "the Pallas TPU kernels")
     ap.add_argument("--interpret", action="store_true",
                     help="run Pallas kernels in interpret mode (CPU CI)")
-    ap.add_argument("--serving", default="pipelined",
-                    choices=("pipelined", "sync"),
-                    help="edge mode serving engine (discrete-event pipeline "
-                         "vs synchronous baseline)")
     ap.add_argument("--queue-depth", type=int, default=2,
-                    help="edge mode per-stage in-queue bound (pipelined only)")
+                    help="edge mode per-stage in-queue bound")
     ap.add_argument("--replicas", default="1",
                     help="edge mode pipeline replica count: an int, or 'auto' "
                          "to maximize summed predicted throughput")
@@ -404,7 +398,7 @@ def main() -> int:
             args.requests, args.nodes, args.seed,
             partitioner=args.partitioner, placer=args.placer, joint=args.joint,
             capacity_frac=args.capacity_frac, width=args.width,
-            serving=args.serving, queue_depth=args.queue_depth,
+            queue_depth=args.queue_depth,
             replicas=replicas, codec=args.codec, tolerance=args.tolerance,
             trace=args.trace, rate=args.rate, duration_s=args.duration,
             autoscale=args.autoscale, max_batch=args.max_batch,

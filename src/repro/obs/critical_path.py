@@ -128,27 +128,14 @@ def analyze_spans(spans) -> dict:
 
 def predicted_times(control):
     """The plan's per-stage/per-hop service times for a control plane's
-    current pipeline -- the same ``core.bottleneck.service_times`` call the
-    engines bind their timing to.  Returns ``(compute_s, link_s)`` or
+    current pipeline -- ``ControlPlane.pipeline_times``, the timing the
+    engines bind their clock to.  Returns ``(compute_s, link_s)`` or
     ``None`` when the dispatcher has no probed view yet."""
-    disp = control.dispatcher
-    pipe = control.pipeline
-    if disp.probed is None or control.desired is None or pipe is None:
+    if (control.dispatcher.probed is None or control.desired is None
+            or control.pipeline is None):
         return None
-    from repro.core.bottleneck import service_times
-
-    graph = control.desired.graph
-    return service_times(
-        [p.partition for p in pipe.pods],
-        [p.node_id for p in pipe.pods],
-        disp.probed.bw,
-        flops_per_node=[n.flops_per_s for n in control.cluster.nodes],
-        in_bytes=graph.in_bytes,
-        out_bytes=graph.layers[-1].out_bytes,
-        dispatcher=disp.leader,
-        compression_ratio=pipe.compression_ratio,
-        codecs=pipe.link_codecs,
-    )
+    compute_s, links = control.pipeline_times()
+    return compute_s, [link.seconds for link in links]
 
 
 def predicted_bottleneck(compute_s, link_s) -> dict:

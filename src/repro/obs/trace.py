@@ -259,24 +259,6 @@ class SpanTracer:
 
 # -- link-window decomposition --------------------------------------------
 
-def split_hop(link_s: float, codec, raw_bytes: int,
-              src_flops: float = 0.0, dst_flops: float = 0.0):
-    """Analytic ``(encode_s, wire_s, decode_s)`` decomposition of one hop.
-
-    Uses the codec cost model (the same one ``dataplane.link_charge_s``
-    charges), so the three parts sum to the hop's total service time.
-    Codec-free hops are pure wire; dead links (inf) stay pure wire so the
-    infinity never leaks into encode/decode.
-    """
-    link_s = float(link_s)
-    if codec is None or not math.isfinite(link_s):
-        return (0.0, link_s, 0.0)
-    enc = float(codec.encode_cost_s(raw_bytes, src_flops))
-    dec = float(codec.decode_cost_s(raw_bytes, dst_flops))
-    wire = max(0.0, link_s - enc - dec)
-    return (enc, wire, dec)
-
-
 def split_window(t0: float, t1: float, parts) -> list[tuple[str, float, float]]:
     """Tile the observed window ``[t0, t1)`` into encode/wire/decode spans
     proportionally to the analytic ``parts`` -- exact when the ride ran to

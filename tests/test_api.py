@@ -221,14 +221,25 @@ def test_joint_path_honors_max_parts():
 
 
 def test_compression_reaches_predicted_throughput():
-    """configure() threads the desired compression into the plan, so
-    SLO checks and metrics() agree with Planner.compile()."""
-    spec1 = _demo_spec()
-    spec2 = _demo_spec(compression_ratio=4.0)
+    """configure() threads the spec's link codec into the plan, so SLO
+    checks and metrics() agree with Planner.compile().  The inter-stage
+    mesh is the slow link here, so compressing it raises the prediction."""
+    from repro.core.model_zoo import demo_mlp
+
+    graph, _ = demo_mlp(d=32)
+    bw = np.full((5, 5), 1e4)
+    bw[0, :] = bw[:, 0] = 1e9  # fast dispatcher round trip
+    np.fill_diagonal(bw, 0.0)
+    cap = np.full(5, graph.total_param_bytes / 3.0)
+    cap[0] = -1.0
+    cluster = ClusterSpec(comm=CommGraph(bw=bw, node_capacity=cap))
+    spec1 = _demo_spec(codec="identity", cluster=cluster)
+    spec2 = _demo_spec(codec="int8", cluster=cluster)
     d1, d2 = deploy(spec1), deploy(spec2)
     assert d2.plan.predicted_throughput > d1.plan.predicted_throughput
-    # same partition/placement: compression only shrinks wire bytes
+    # same partition: the codec only shrinks wire bytes
     assert d2.plan.partition.cuts == d1.plan.partition.cuts
+    assert "int8" in d2.plan.codecs and "int8" not in d1.plan.codecs
 
 
 # ---------------------------------------------------------------------------
@@ -504,10 +515,7 @@ def test_arrival_spec_validation_codes():
     assert "bad_arrival" in _codes(_demo_spec(arrival=ArrivalSpec(rate=0.0)))
     assert "bad_arrival" in _codes(
         _demo_spec(arrival=ArrivalSpec(duration_s=-1.0)))
-    # open-loop arrivals need the pipelined engine
-    sync = _demo_spec(serving="sync", arrival=ArrivalSpec())
-    assert "bad_serving" in _codes(sync)
-    assert not {"unknown_trace", "bad_arrival", "bad_serving"} & _codes(
+    assert not {"unknown_trace", "bad_arrival"} & _codes(
         _demo_spec(arrival=ArrivalSpec(trace="bursty", rate=50.0,
                                        duration_s=2.0)))
 

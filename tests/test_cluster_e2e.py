@@ -5,6 +5,8 @@ inference -> node failure -> recovery -> inference again -> model-version
 update -> redeploy.  The executor is a real jnp MLP so outputs are checked
 end-to-end, not just orchestration state."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ from repro.cluster import ArtifactStore, Dispatcher, EdgeCluster, ModelWatcher
 from repro.core.graph import chain
 from repro.core.placement import CommGraph
 from repro.core.simulate import random_cluster
+from repro.dataplane import get_codec
 
 
 def _mlp_setup(n_layers=8, d=16, seed=0):
@@ -84,12 +87,17 @@ def test_compression_reduces_bottleneck():
     cluster = _cluster()
     disp = Dispatcher(cluster, ArtifactStore_tmp(), seed=1)
     plan = disp.configure(g, version=0, capacity=3 * 16 * 16 * 4)
+    assert set(plan.codecs) == {"identity"}
     plain = disp.deploy(plan, executor)
-    comp = disp.deploy(plan, executor, compression_ratio=2.0)
+    inner = ("int8",) * (plan.n_parts - 1)
+    comp = disp.deploy(
+        dataclasses.replace(plan, codecs=("identity", *inner, "identity")),
+        executor)
     x = jnp.ones((4, 16))
     _, t0 = plain.run(x)
     _, t1 = comp.run(x)
-    assert t1.bottleneck_s == pytest.approx(t0.bottleneck_s / 2.0)
+    assert t1.bottleneck_s == pytest.approx(
+        t0.bottleneck_s * get_codec("int8").wire_ratio())
 
 
 def test_model_watch_redeploys():

@@ -3,8 +3,8 @@ joint codec x placement assignment, and the engine's end-to-end pinning.
 
 The anchor tests are the last two groups: every registered codec (plus
 ``"auto"``) deployed on a bandwidth-constrained cluster must measure within
-5% of ``Plan.predicted_throughput`` (the engine and the planner share
-``core.bottleneck.service_times``), and a lossy codec must *really* alter
+5% of ``Plan.predicted_throughput`` (the engine and the planner read the
+same ``dataplane.hops`` records), and a lossy codec must *really* alter
 the activations crossing links -- the transform runs in the serving path,
 not just in the byte model.
 """
@@ -344,6 +344,39 @@ def test_lossy_codec_really_transforms_the_activations():
         outs[codec] = np.asarray(req.result, np.float32)
     assert not np.array_equal(outs["identity"], outs["int8"])
     assert np.max(np.abs(outs["identity"] - outs["int8"])) < 0.05
+
+
+@pytest.mark.parametrize("codec", ["identity", "fp16", "int8", "topk-sparse"])
+def test_every_reader_takes_the_one_hop_record(codec):
+    """Each hop is costed once: the record's window is the codec's link
+    charge and its wire bytes the codec's, and the engine's link report,
+    ``service_times`` and ``InferencePipeline.wire_bytes`` all read it."""
+    d = _deploy(codec)
+    control = d.control
+    pipe, disp = control.pipeline, control.dispatcher
+    compute_s, links = control.pipeline_times()
+    bw, flops = disp.probed.bw, disp.node_flops()
+    assert len(links) == len(pipe.pods) + 1
+    c = get_codec(codec)
+    for h, link in enumerate(links[1:-1], start=1):
+        assert link.codec.name == codec
+        assert link.raw_bytes == pipe.boundary_bytes[h - 1]
+        assert link.wire_bytes == c.wire_bytes(link.raw_bytes)
+        assert link.seconds == link_charge_s(
+            c, link.raw_bytes, float(bw[link.src, link.dst]),
+            src_flops=flops[link.src], dst_flops=flops[link.dst])
+        assert pipe.wire_bytes(h - 1) == link.wire_bytes
+    assert [(r["codec"], r["raw_bytes"], r["wire_bytes"], r["link_s"])
+            for r in d.loop.metrics()["links"]] == [
+        (link.codec.name, link.raw_bytes, link.wire_bytes, link.seconds)
+        for link in links]
+    graph = control.desired.graph
+    assert service_times(
+        [p.partition for p in pipe.pods], pipe.path(), bw,
+        flops_per_node=flops, in_bytes=graph.in_bytes,
+        out_bytes=graph.layers[-1].out_bytes, dispatcher=disp.leader,
+        codecs=d.plan.codecs,
+    ) == (compute_s, [link.seconds for link in links])
 
 
 def test_engine_reports_per_link_compression_and_utilization():

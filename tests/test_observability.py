@@ -34,7 +34,8 @@ from repro.core.model_zoo import demo_mlp
 from repro.obs import Journal, analyze_spans, percentile
 from repro.obs.critical_path import request_attribution
 from repro.obs.metrics import MetricsRegistry, validate_snapshot
-from repro.obs.trace import SpanTracer, split_hop, split_window
+from repro.dataplane.hops import split_hop
+from repro.obs.trace import SpanTracer, split_window
 
 from tests._hypothesis_compat import given, settings, st
 
@@ -117,16 +118,6 @@ def test_disabled_tracing_leaves_no_surface():
     assert d.chrome_trace() is None
     assert d.attribution() is None
     assert d.metrics()["observability"]["trace"] is None
-
-
-def test_sync_loop_emits_tiling_spans():
-    d = _serve(_deploy(serving="sync"), 8)
-    for req in d.loop.completed:
-        spans = d.tracer.spans_for(req.req_id)
-        assert spans
-        _assert_contiguous(spans)
-        covered = sum(s.duration_s for s in spans)
-        assert abs(covered - req.latency_s) <= 1e-8
 
 
 def _replicated(sample=1.0, seed=0):
@@ -416,11 +407,11 @@ def test_profiler_spans_time_each_stage_call_inside_its_step(profiled):
 
 def test_profiler_spans_time_each_coded_hop(profiled):
     d, events = profiled
-    coded = [h for h, c in enumerate(d.loop._link_codecs) if c is not None]
+    coded = [h for h, link in enumerate(d.loop._hops) if link.codec is not None]
     assert coded
     spans = [e["args"] for e in events if e["name"] == "seifer.codec"]
     assert sorted(a["hop"] for a in spans) == sorted(coded * BATCHES)
-    assert all(a["codec"] == d.loop._link_codecs[a["hop"]].name for a in spans)
+    assert all(a["codec"] == d.loop._hops[a["hop"]].codec.name for a in spans)
     assert "int8" in {a["codec"] for a in spans}
     assert {a["op"] for a in spans} <= {"transcode", "encode"}
 
